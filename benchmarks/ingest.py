@@ -14,7 +14,7 @@ where the compaction modes are:
   argsort  — chain + the legacy O(R log R) ``compact_fixed_argsort``
              stable-sort gather (what ``compact_fixed`` used to be).
   fused    — the single-pass path: O(R) cumsum scatter on the jnp engine,
-             in-kernel tile pack + offset-stitch gather launch on pallas.
+             in-kernel row pack + stitched survivor DMA (one launch) on pallas.
 
 plus the tile-statistics skip-tier sweep (jnp engine):
 
@@ -257,6 +257,8 @@ def main():
             os.environ["XLA_FLAGS"] = (
                 f"{flags} --xla_force_host_platform_device_count="
                 f"{args.devices}")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.batch_rows = min(args.batch_rows, 16384)
         args.steps = min(args.steps, 5)

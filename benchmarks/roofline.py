@@ -32,17 +32,14 @@ def filter_ingest_model(*, n_cols: int = 4, tile: int = 2048,
                         key+index passes over the tile's 4-byte lanes),
                         then a second FULL-WIDTH gather read of the
                         columns and the cap-width packed write.
-    fused (in-kernel) : the tile is packed while resident in VMEM — the
-                        chain pass additionally writes the within-tile
-                        packed survivors (C·T·B) + one i32 count; the
-                        second (gather) launch then moves only SURVIVOR
-                        data — the per-tile prefix, rounded up to the
-                        128-lane copy quantum — at its exclusive offset.
-                        No sort runs anywhere, and the full-width columns
-                        are never read again. (The CPU interpret-mode
-                        stand-in moves whole tiles in launch 2; a Mosaic
-                        lowering DMAs the counted prefix via scalar
-                        prefetch, which is what this model charges.)
+    fused (in-kernel) : ONE launch — the chain pass packs each tile's
+                        survivors while the tile is resident in VMEM and
+                        DMAs them to the HBM output in TILE-row blocks,
+                        so on top of chain-only it writes only SURVIVOR
+                        data (rounded up to the 128-lane quantum; the DMA
+                        moves the output's padded sublane rows). No sort
+                        runs anywhere, and the full-width columns are
+                        never read again.
     skip tier         : with ``skip_fraction`` of 128-row sub-tiles
                         provably decided by zone maps (``skip_pass_fraction``
                         of THOSE provably passing), the fused launch is
@@ -59,8 +56,14 @@ def filter_ingest_model(*, n_cols: int = 4, tile: int = 2048,
                         summary overhead when nothing is provable
                         (skip_fraction = 0) — the graceful-shuffle case.
     """
+    import importlib
     import math
 
+    # the packed output's padded sublane rows (what each survivor DMA
+    # moves); the package re-exports the jitted ``filter_chain`` under the
+    # kernel module's name, so import the module itself
+    dma_rows = importlib.import_module(
+        "repro.kernels.filter_chain.filter_chain").dma_rows
     col_bytes = n_cols * tile * dtype_bytes
     mask_bytes = tile                                   # i8 mask lane
     chain_only = col_bytes + mask_bytes
@@ -69,14 +72,12 @@ def filter_ingest_model(*, n_cols: int = 4, tile: int = 2048,
     unfused = chain_only + sort_bytes + col_bytes + col_bytes
     # survivor prefix, quantized to the 128-lane copy granule
     p_quant = math.ceil(pass_rate * tile / 128) * 128 / tile
-    surv = p_quant * col_bytes
-    # per-launch split (the repro.analysis.kernel_audit contract: the
-    # captured BlockSpec geometry must reproduce these terms exactly at
-    # pass_rate=1.0 — launch 1 = chain read + mask + packed tile + i32
-    # count; launch 2 = offset + survivor read + stitched write)
-    fused_launch1 = chain_only + col_bytes + 4
-    fused_launch2 = 4 + surv + surv
-    fused = fused_launch1 + fused_launch2
+    out_bytes = dma_rows(n_cols) * tile * dtype_bytes    # one packed block
+    surv = p_quant * out_bytes
+    # the repro.analysis.kernel_audit contract: the captured BlockSpec
+    # geometry of every chain launch (fused or not) must stream exactly
+    # chain_only; the survivor DMA is the fused launch's only addition
+    fused = chain_only + surv
 
     # ---- skip tier: tile-summary traffic + decided-sub-tile read savings
     sub_tiles = tile // 128                             # 128-row sub-tiles
@@ -90,25 +91,23 @@ def filter_ingest_model(*, n_cols: int = 4, tile: int = 2048,
     # are DMA-gated out); pass sub-tiles skip predicate math but are still
     # copied through VMEM to the packed output
     read_frac = 1.0 - fail_frac
-    surv_skip = min(p_quant + pass_frac, 1.0) * col_bytes
-    fused_skip = (summary_bytes + read_frac * col_bytes + mask_bytes
-                  + read_frac * col_bytes + 4) + (4 + surv_skip + surv_skip)
+    surv_skip = min(p_quant + pass_frac, 1.0) * out_bytes
+    fused_skip = summary_bytes + read_frac * col_bytes + mask_bytes \
+        + surv_skip
     return {
         "n_cols": n_cols, "tile": tile, "pass_rate": pass_rate,
         "bytes_chain_only": chain_only,
         "bytes_unfused_argsort": unfused,
         "bytes_fused": fused,
-        "bytes_fused_launch1": fused_launch1,
-        "bytes_fused_launch2": fused_launch2,
         "fused_traffic_ratio": fused / unfused,
         "skip_fraction": skip_fraction,
         "bytes_summary": summary_bytes,
         "bytes_fused_skip": fused_skip,
         "skip_traffic_ratio": fused_skip / fused,
-        "note": "fused removes the sort entirely and touches survivor "
-                "bytes only in launch 2; at low pass-rates the gather "
-                "launch is nearly free; the skip tier trades a ~1% "
-                "summary pass for not reading decided tiles at all",
+        "note": "fused removes the sort and the second pass entirely and "
+                "writes survivor bytes only; at low pass-rates it costs "
+                "about what the chain alone costs; the skip tier trades a "
+                "~1% summary pass for not reading decided tiles at all",
     }
 
 _NOTES = {

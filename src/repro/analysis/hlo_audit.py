@@ -116,7 +116,7 @@ def audit_plan(plan, mesh=None, *, rows_per_shard: int = 512,
     state = session.init_state()
 
     step_free, expect_present = _expectations(plan, shards)
-    step_text = session.compiled_step_text(state, batch)
+    step_text = session.compiled_step_texts(state, batch)["step"]
     diags += audit_step_text(step_text, plan, num_shards=shards)
 
     # the boundary exchange / retune module: deferred CENTRALIZED must show
@@ -132,15 +132,6 @@ def audit_plan(plan, mesh=None, *, rows_per_shard: int = 512,
                 "merged and every shard re-ranks on local evidence only",
                 "the exchange_update psum was dropped; check "
                 "reduce_stats wiring under shard_map"))
-
-    # compact / tokenize module (unsharded path lowers them separately)
-    if plan.compact and not session.sharded:
-        f = session.filter
-        cap = f.resolve_capacity(batch.shape[1])
-        compact_text = f._jit_compact.lower(
-            state, batch, capacity=cap).compile().as_text()
-        diags += audit_step_text(compact_text, plan, num_shards=shards,
-                                 location="plan:compact-hlo")
 
     if plan.tokenize is not None:
         diags += _audit_tokenizer(plan, rows_per_shard, shards)

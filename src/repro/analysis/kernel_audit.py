@@ -1,17 +1,14 @@
 """Static memory-safety verifier for the Pallas filter-chain kernels.
 
 The kernels in ``kernels/filter_chain/`` hand-write grid/BlockSpec index
-arithmetic; nothing checked it statically, and the ROADMAP's Mosaic
-prefix-DMA gather means more of it is coming. This pass captures every
+arithmetic; nothing checked it statically. This pass captures every
 ``pallas_call`` launch geometry (grid, BlockSpecs, operand shapes) by
 intercepting the launch — the kernel body never runs — and proves, for a
 sweep of supported (rows, cols, capacity, tile) shapes:
 
   kernel-oob-access        error    a BlockSpec index map demands a block
                                     outside the (tile-padded) array for
-                                    some grid point, or the gather ring
-                                    lacks the TILE of slack its guarded
-                                    dynamic store relies on
+                                    some grid point
   kernel-misaligned-tile   error    a VMEM block whose lane (last) dim is
                                     neither a multiple of 128 nor the
                                     array's full lane extent — Mosaic
@@ -33,17 +30,20 @@ sweep of supported (rows, cols, capacity, tile) shapes:
                                     STAT_TILE == skip_tier.SKIP_TILE, ...)
   kernel-interpret-only    warning  a construct that runs under
                                     ``interpret=True`` but will not lower
-                                    to Mosaic as written: a dynamic lane
-                                    offset (``pl.ds`` with a traced start
-                                    in the minormost index slot) — the
-                                    safety net the prefix-DMA lowering
-                                    lands behind
+                                    to Mosaic as written: a ref indexed
+                                    at a dynamic lane offset (``pl.ds``
+                                    with a traced start in the minormost
+                                    index slot) that is not declared
+                                    128-aligned by ``pl.multiple_of``
 
-``capture_launches`` sweeps the real entry points (chain with/without
-compaction, skip-tier decisions on/off, the compact gather, the
-zone-map stats pre-pass); ``audit_launches`` runs the geometry checks on
-any list of ``Launch`` records, which is what the seeded-defect tests
-drive directly.
+Blocks in SMEM and outputs left in HBM (``memory_space=pl.ANY``, written
+by the kernel's own DMAs) are outside the VMEM geometry checks; the
+compile rehearsals in ``tests/test_tpu_compile.py`` cover what Mosaic
+itself refuses. ``capture_launches`` sweeps the real entry points (chain
+with/without fused compaction, skip-tier decisions on/off, the zone-map
+stats pre-pass); ``audit_launches`` runs the geometry checks on any list
+of ``Launch`` records, which is what the seeded-defect tests drive
+directly.
 """
 
 from __future__ import annotations
@@ -80,9 +80,9 @@ DEFAULT_SHAPES = (
 class BlockInfo:
     """One BlockSpec, reduced to what the geometry checks need."""
 
-    block_shape: tuple | None        # None: whole array (SMEM scalars)
+    block_shape: tuple | None        # None: whole array; squeezed dims → 1
     index_map: object                # callable grid→block indices, or None
-    memory_space: str                # "smem" | "vmem"
+    memory_space: str                # "smem" | "vmem" | "any" (left in HBM)
 
 
 @dataclasses.dataclass
@@ -99,13 +99,14 @@ class Launch:
 
 
 def _space_of(spec) -> str:
-    return "smem" if "smem" in str(getattr(spec, "memory_space", "")).lower() \
-        else "vmem"
+    space = str(getattr(spec, "memory_space", "")).lower()
+    return space if space in ("smem", "any") else "vmem"
 
 
 def _info_of(spec) -> BlockInfo:
     shape = getattr(spec, "block_shape", None)
-    return BlockInfo(None if shape is None else tuple(shape),
+    return BlockInfo(None if shape is None
+                     else tuple(1 if d is None else d for d in shape),
                      getattr(spec, "index_map", None), _space_of(spec))
 
 
@@ -195,12 +196,10 @@ def capture_launches(shapes=DEFAULT_SHAPES) -> list[Launch]:
         with _Recorder() as rec:
             fc.filter_chain_pallas(cols, specs, perm, meta, tile=tile)
             fc.filter_chain_pallas(cols, specs, perm, meta, tile=tile,
-                                   compact=True)
+                                   compact=True, capacity=cap)
             fc.filter_chain_pallas(cols, specs, perm, meta, tile=tile,
-                                   compact=True, skip_decisions=decisions)
-            fc.compact_gather_pallas(cols, jnp.zeros((rows_p // tile,),
-                                                     jnp.int32),
-                                     cap, tile=tile)
+                                   compact=True, capacity=cap,
+                                   skip_decisions=decisions)
             fc.tile_stats_pallas(cols, tile=tile)
         for launch in rec.launches:
             launch.ctx = dict(ctx)
@@ -221,7 +220,7 @@ def _check_spec(launch: Launch, kind: str, i: int, spec: BlockInfo,
                 arr_shape: tuple, dtype: str) -> list[Diagnostic]:
     diags: list[Diagnostic] = []
     loc = f"kernel:{launch.name}:{kind}[{i}]"
-    if spec.memory_space == "smem" or spec.block_shape is None:
+    if spec.memory_space != "vmem" or spec.block_shape is None:
         return diags
     block = spec.block_shape
     if len(block) != len(arr_shape):
@@ -280,7 +279,7 @@ def _vmem_working_set(launch: Launch) -> int:
     for spec, (shape, dtype) in zip(
             launch.in_specs + launch.out_specs,
             launch.in_shapes + launch.out_shapes):
-        if spec.memory_space == "smem":
+        if spec.memory_space != "vmem":
             continue
         block = spec.block_shape if spec.block_shape is not None else shape
         total += _block_bytes(block, dtype)
@@ -305,22 +304,6 @@ def audit_launches(launches) -> list[Diagnostic]:
                 f"double-buffered per-grid-step working set {ws} B "
                 f"exceeds the {VMEM_BUDGET} B VMEM budget",
                 "shrink the tile or split the launch"))
-
-        # the gather's guarded dynamic store (off < capacity, extent TILE)
-        # is only in-bounds because the output ring carries TILE of slack
-        if "compact_gather" in launch.name and launch.ctx:
-            cap, tile = launch.ctx["capacity"], launch.ctx["tile"]
-            width = launch.out_shapes[0][0][-1]
-            if width < cap + tile:
-                diags.append(Diagnostic(
-                    "kernel-oob-access", "error",
-                    f"kernel:{launch.name}:out[0]",
-                    f"output ring width {width} < capacity {cap} + tile "
-                    f"{tile}: the guarded dynamic store pl.ds(off, "
-                    f"{tile}) with off ≤ {cap - 1} would write past the "
-                    "buffer",
-                    "allocate [C, capacity + tile] and slice the ring "
-                    "down after the launch"))
     return diags
 
 
@@ -344,40 +327,29 @@ def _load_roofline():
     return mod
 
 
-def _chain_geometry_bytes(launch: Launch) -> tuple[int, bool]:
-    """(per-grid-step data bytes, is_compact) for a chain launch.
+def _streamed_bytes(launch: Launch) -> int:
+    """Per-grid-step bytes a launch streams through VMEM blocks.
 
-    Data traffic = the column tile in + the mask out (+ the packed tile
-    and i32 count with in-kernel compaction). The f32 monitor counters
-    (active/cut/gcut/nmon) are bookkeeping lanes the byte model
-    deliberately ignores — a few hundred bytes against megabyte tiles.
+    For a chain launch: the column tile in + the int8 mask out. The
+    monitor counters live in SMEM (a few hundred bytes against megabyte
+    tiles), and the fused compaction's survivors leave by the kernel's
+    own DMA to an HBM output — neither is BlockSpec geometry.
     """
-    tile = launch.ctx["tile"]
-    total = 0
-    compact = False
-    for spec, (shape, dtype) in zip(launch.in_specs, launch.in_shapes):
-        if spec.memory_space != "smem":
-            total += _block_bytes(spec.block_shape, dtype)    # column tile
-    for spec, (shape, dtype) in zip(launch.out_specs, launch.out_shapes):
-        block = spec.block_shape
-        if dtype == "int8":                                   # mask lane
-            total += _block_bytes(block, dtype)
-        elif dtype == "int32":                                # tile count
-            total += _block_bytes(block, dtype)
-            compact = True
-        elif dtype == "float32" and block[-1] == tile:        # packed tile
-            total += _block_bytes(block, dtype)
-    return total, compact
+    return sum(_block_bytes(spec.block_shape, dtype)
+               for spec, (_, dtype) in zip(
+                   launch.in_specs + launch.out_specs,
+                   launch.in_shapes + launch.out_shapes)
+               if spec.memory_space == "vmem")
 
 
 def crosscheck_roofline(launches) -> list[Diagnostic]:
     """The captured launch geometry and the analytic byte model must agree.
 
-    At pass_rate=1.0 the model's survivor quantization is exact, so each
-    launch family has a closed-form prediction the geometry must match
-    byte-for-byte: chain-only = C·T·B + T; fused launch 1 adds the packed
-    tile + count; fused launch 2 = offset + packed read + stitched write;
-    the stats pre-pass = the summary write half of ``bytes_summary``.
+    Each launch family has a closed-form prediction the geometry must
+    match byte-for-byte: every chain launch, fused or not, streams
+    chain-only = C·T·B + T (the fused survivors are the model's DMA
+    term); the stats pre-pass = the summary write half of
+    ``bytes_summary``.
     """
     roofline = _load_roofline()
     if roofline is None:
@@ -404,23 +376,10 @@ def crosscheck_roofline(launches) -> list[Diagnostic]:
         model = roofline.filter_ingest_model(n_cols=n_cols, tile=tile,
                                              pass_rate=1.0)
         if launch.name.startswith("adaptive_filter_chain"):
-            geom, compact = _chain_geometry_bytes(launch)
-            if compact:
-                if geom != model["bytes_fused_launch1"]:
-                    drift(launch.name, "fused launch 1 (chain+pack)",
-                          geom, model["bytes_fused_launch1"])
-            elif geom != model["bytes_chain_only"]:
-                drift(launch.name, "chain-only launch", geom,
+            geom = _streamed_bytes(launch)
+            if geom != model["bytes_chain_only"]:
+                drift(launch.name, "chain launch (streamed blocks)", geom,
                       model["bytes_chain_only"])
-        elif "compact_gather" in launch.name:
-            packed_block = next(
-                s.block_shape for s in launch.in_specs
-                if s.memory_space != "smem")
-            read = _block_bytes(packed_block, "float32")
-            geom = 4 + read + read    # offset + packed read + stitch write
-            if geom != model["bytes_fused_launch2"]:
-                drift(launch.name, "fused launch 2 (gather)", geom,
-                      model["bytes_fused_launch2"])
         elif "tile_stats" in launch.name:
             geom = sum(_block_bytes(s.block_shape, d)
                        for s, (_, d) in zip(launch.out_specs,
@@ -437,15 +396,24 @@ def _is_static(node: ast.AST) -> bool:
         isinstance(node, ast.UnaryOp) and _is_static(node.operand))
 
 
-def scan_interpret_only(source_path: Path | None = None) -> list[Diagnostic]:
-    """Flag dynamic lane offsets: ``pl.load``/``pl.store`` whose minormost
-    index is ``pl.ds`` with a traced start.
+def _is_pl_call(node: ast.AST, attr: str) -> bool:
+    return (isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr == attr
+            and isinstance(node.func.value, ast.Name)
+            and node.func.value.id == "pl")
 
-    Interpret mode executes them as plain array indexing; Mosaic requires
-    lane offsets to be static/aligned — the real lowering replaces this
-    with a scalar-prefetched DMA, which is exactly the ROADMAP item this
-    screen is the safety net for. A dynamic SUBLANE slice (e.g. the
-    chain's column select) lowers fine and is not flagged.
+
+def scan_interpret_only(source_path: Path | None = None) -> list[Diagnostic]:
+    """Flag refs indexed at an unaligned dynamic lane offset: a subscript
+    (load, store, or a DMA's ``.at[...]`` window) whose minormost index
+    is ``pl.ds`` over a traced start.
+
+    Interpret mode executes them as plain array indexing; Mosaic needs
+    to prove a dynamic lane offset is a multiple of 128, so a start
+    wrapped in ``pl.multiple_of(...)`` passes, as does a static one. A
+    dynamic SUBLANE slice (e.g. the chain's column select) lowers fine
+    and is not flagged.
     """
     if source_path is None:
         fc = importlib.import_module(
@@ -454,29 +422,25 @@ def scan_interpret_only(source_path: Path | None = None) -> list[Diagnostic]:
     tree = ast.parse(source_path.read_text(), filename=str(source_path))
     diags: list[Diagnostic] = []
     for node in ast.walk(tree):
-        if not (isinstance(node, ast.Call)
-                and isinstance(node.func, ast.Attribute)
-                and node.func.attr in ("load", "store")
-                and isinstance(node.func.value, ast.Name)
-                and node.func.value.id == "pl" and len(node.args) >= 2):
+        if not isinstance(node, ast.Subscript):
             continue
-        idx = node.args[1]
-        elts = idx.elts if isinstance(idx, ast.Tuple) else [idx]
-        last = elts[-1]
-        if (isinstance(last, ast.Call)
-                and isinstance(last.func, ast.Attribute)
-                and last.func.attr == "ds" and last.args
-                and not _is_static(last.args[0])):
-            diags.append(Diagnostic(
-                "kernel-interpret-only", "warning",
-                f"{source_path.name}:{node.lineno}",
-                f"pl.{node.func.attr} with a DYNAMIC lane offset "
-                "(pl.ds over a traced start in the minormost slot) — "
-                "runs under interpret=True, will not lower to Mosaic "
-                "as written",
-                "gate the Mosaic build on the scalar-prefetch DMA "
-                "lowering (ROADMAP: prefix-DMA gather); interpret-mode "
-                "use is sanctioned meanwhile"))
+        idx = node.slice
+        last = idx.elts[-1] if isinstance(idx, ast.Tuple) else idx
+        if not (_is_pl_call(last, "ds") and last.args):
+            continue
+        start = last.args[0]
+        if _is_static(start) or _is_pl_call(start, "multiple_of"):
+            continue
+        diags.append(Diagnostic(
+            "kernel-interpret-only", "warning",
+            f"{source_path.name}:{node.lineno}",
+            "ref indexed at a DYNAMIC lane offset (pl.ds over a traced "
+            "start in the minormost slot) that is not declared aligned — "
+            "runs under interpret=True, will not lower to Mosaic as "
+            "written",
+            "align the offset to 128 lanes and say so with "
+            "pl.multiple_of, or roll the vector instead (see the fused "
+            "compaction's stitch)"))
     return diags
 
 

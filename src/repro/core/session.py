@@ -47,7 +47,7 @@ from repro.core.adaptive_filter import (AdaptiveFilter, AdaptiveFilterConfig,
                                         StepMetrics)
 from repro.core.ordering import OrderState
 from repro.core.plan import FilterPlan, TokenizeSpec
-from repro.core.sharded import ShardedAdaptiveFilter
+from repro.core.sharded import ShardedAdaptiveFilter, data_mesh
 
 log = logging.getLogger(__name__)
 
@@ -303,9 +303,8 @@ class FilterSession:
                         "skip_tier needs the unsharded execution layer: "
                         "a mesh forces shard_map, whose static shapes the "
                         "per-step ambiguous-tile sync cannot drive")
-                import jax
                 if mesh is None:
-                    mesh = jax.make_mesh((plan.shards,), (plan.axis_name,))
+                    mesh = data_mesh(plan.shards, plan.axis_name)
                 elif plan.axis_name in mesh.axis_names \
                         and int(mesh.shape[plan.axis_name]) != plan.shards:
                     raise ValueError(
@@ -537,7 +536,7 @@ class FilterSession:
             import jax
             from jax.sharding import PartitionSpec as P
 
-            from repro.compat import shard_map
+            from repro.core.sharded import shard_map
             from repro.data import tokenizer
 
             ts = self.plan.tokenize
@@ -659,13 +658,38 @@ class FilterSession:
         return out
 
     # ------------------------------------------------------------ analysis
-    def compiled_step_text(self, state: OrderState, batch) -> str:
-        """Compiled HLO of one step (collective-freedom assertions)."""
+    def compiled_step_texts(self, state: OrderState, batch) -> dict:
+        """Compiled HLO of every jitted module ``step`` runs for this plan
+        (``"triage"`` when the skip tier is on, and ``"step"``: the plain,
+        compacting or skip-tier step the plan dispatches to) — what the
+        collective-freedom audits and a chip run's compiled-kernel check
+        grep."""
+        import jax.numpy as jnp
+
+        cols = jnp.asarray(batch, jnp.float32)
         if self.sharded:
-            return self.filter.compiled_text(state, batch)
-        import jax
-        return jax.jit(self.filter.step).lower(
-            state, batch).compile().as_text()
+            return {"step": self.filter.compiled_text(state, cols)}
+        f, n = self.filter, int(cols.shape[1])
+        texts, info = {}, None
+        mode = self._skip_step_mode()
+        if mode != "off":
+            bloom = mode == "zonemap+bloom"
+            texts["triage"] = f._jit_triage.lower(
+                cols, bloom=bloom).compile().as_text()
+            info = f._jit_triage(cols, bloom=bloom)
+            skip = dict(amb_cap=f.skip_amb_cap(info, n))
+            decisions = (info.pass_tiles, info.fail_tiles)
+        if self.plan.compact:
+            cap = f.resolve_capacity(n)
+            lowered = f._jit_compact.lower(state, cols, capacity=cap) \
+                if info is None else f._jit_skip_compact.lower(
+                    state, cols, *decisions, capacity=cap, **skip)
+        elif info is not None:
+            lowered = f._jit_skip.lower(state, cols, *decisions, **skip)
+        else:
+            lowered = f.jit_step.lower(state, cols)
+        texts["step"] = lowered.compile().as_text()
+        return texts
 
     def compiled_exchange_text(self, state: OrderState) -> str:
         return self.filter.compiled_exchange_text(state) if self.sharded \

@@ -52,12 +52,27 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.adaptive_filter import (AdaptiveFilter, AdaptiveFilterConfig,
                                         drive_exchange)
 from repro.core.ordering import OrderState
 from repro.core.plan import validate_combo
 from repro.core.predicates import Predicate
+
+
+def data_mesh(n_shards: int, axis_name: str) -> jax.sharding.Mesh:
+    """1-D data mesh with an ``Auto`` axis (``jax.make_mesh`` defaults to
+    ``Explicit``): the shard_mapped step places its own blocks, and the
+    host-side glue around it must not carry sharding in its types."""
+    return jax.make_mesh((n_shards,), (axis_name,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-axes type check: the ordering
+    math's ``lax.cond`` epoch boundary returns freshly reset (unvarying)
+    accumulators from one branch and the shard's own from the other."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def stack_states(state: OrderState, num_shards: int) -> OrderState:
@@ -87,7 +102,7 @@ class ShardedAdaptiveFilter:
         cfg = config or AdaptiveFilterConfig()
         self.inner = AdaptiveFilter(predicates, cfg, axis_names=(axis_name,))
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), (axis_name,))
+            mesh = data_mesh(jax.device_count(), axis_name)
         if axis_name not in mesh.axis_names:
             raise ValueError(f"mesh has no axis {axis_name!r}: "
                              f"{mesh.axis_names}")

@@ -23,38 +23,38 @@ adaptation (DESIGN §3) processes rows in VMEM tiles:
     paper's cost accounting survives vectorization bit-exactly.
 
 Memory layout: predicate spec arrays (i32/f32[P]) live in SMEM (scalar
-dispatch data); column tiles and outputs in VMEM. The CNF group ids ride
-twice: as an SMEM i32[P] vector for the perm-ordered chain lane (the
-permutation is dynamic) and as a STATIC python tuple for the monitor lane's
-group reduction (user order → unrolled at trace time). All intra-kernel
-compute is 2D (1, TILE)-shaped for VPU lane alignment; TILE is a multiple
-of 128.
+dispatch data); column tiles in VMEM. Each grid step first relayouts its
+(C, TILE) tile into a (C, TILE/128, 128) VMEM scratch, so every
+predicate reads one column as full (8, 128) vregs by a dynamic index on
+the leading dim. Row masks travel as f32 0/1 lanes of that (TILE/128,
+128) shape — never as bool vectors through ``cond``/``switch``/``where``,
+which Mosaic cannot legalize — and leave the kernel as an int8 block.
+Per-tile counters are scalars, so they go to SMEM output blocks. The CNF
+group ids ride twice: as an SMEM i32[P] vector for the perm-ordered chain
+lane (the permutation is dynamic) and as a STATIC python tuple for the
+monitor lane's group reduction (user order → unrolled at trace time).
 
-Grid-step cost model (for §Roofline): bytes/tile = C·TILE·4 in + TILE out;
-with in-kernel compaction the tile additionally writes the within-tile
-packed survivors (C·TILE·4) and one i32 count; the second (gather) launch
-then reads only survivor data — p·C·TILE·4 per tile at pass-rate p — plus
-the T-entry offset vector, never the full batch again.  FLOPs/tile ≈
-TILE · Σ_{k ≤ stop} cost(perm[k]) — memory-bound at ~0.25–2 FLOP/byte
-unless expensive (HASHMIX) predicates dominate.
-(``benchmarks/roofline.py::filter_ingest_model`` renders this model.)
+Grid-step cost model (for §Roofline): bytes/tile = C·TILE·4 in + TILE
+mask out; with fused compaction the tile additionally writes its
+survivors (≈ p·C·TILE·4 at pass-rate p) — the columns are read once and
+never again. FLOPs/tile ≈ TILE · Σ_{k ≤ stop} cost(perm[k]) —
+memory-bound at ~0.25–2 FLOP/byte unless expensive (HASHMIX) predicates
+dominate. (``benchmarks/roofline.py::filter_ingest_model`` renders this
+model.)
 
-Single-pass compaction (two launches, no sort):
-
-  launch 1 (this kernel, ``compact=True``): while the (C, TILE) tile is
-    still in VMEM, each grid step computes every survivor's within-tile
-    slot as its exclusive mask cumsum (an O(TILE) scan — the argsort the
-    jnp path used to pay is gone), scatters survivors to the front of the
-    tile's own slot in the packed output, and emits the tile's survivor
-    count;
-  glue: an O(n_tiles) exclusive cumsum of the counts (XLA, a few hundred
-    ints) turns per-tile slots into global offsets;
-  launch 2 (``compact_gather_pallas``): one grid step per tile stores the
-    packed tile at its global offset into the [C, cap + TILE] output ring.
-    Stores overlap by construction — tile t's zero tail is overwritten by
-    tile t+1's survivors (the TPU grid is sequential) — so the result is
-    the densely packed survivor buffer without reading the full-width
-    columns a second time.
+Fused compaction (``compact=True``, one launch, no sort, sequential
+grid): while the tile is resident in VMEM, each 128-row lane row of the
+tile is packed in-register — an inclusive lane cumsum (7 ``roll`` steps)
+gives every survivor's slot, a 7-step binary search over it gives every
+output lane its source lane, and a lane gather (``take_along_axis``)
+moves the values. The packed rows are then appended to a staging buffer
+at the running survivor count: a dynamic ``roll`` by ``count % 128`` and
+two stores at 128-aligned offsets stitch each row onto the stream. Every
+TILE survivors the stage is copied out and DMA'd to the HBM output at the
+next TILE-aligned offset; the last grid step flushes the partial stage
+with a ``fill`` tail and DMAs ``fill`` blocks over the rest of the
+capacity window. Offsets, the stage count and the in-flight DMA flag are
+SMEM scratch carried across grid steps.
 """
 
 from __future__ import annotations
@@ -70,6 +70,7 @@ from repro.core import predicates as pred_lib
 
 DEFAULT_TILE = 2048  # rows per grid step; multiple of 128 (VPU lanes)
 STAT_TILE = 128      # zone-map statistics granularity (= skip_tier.SKIP_TILE)
+LANES = 128
 
 
 def _stats_kernel(cols_ref, min_ref, max_ref, *, tile: int):
@@ -126,132 +127,171 @@ def tile_stats_pallas(columns: jnp.ndarray, *, tile: int = DEFAULT_TILE,
     return _flat(mins), _flat(maxs)
 
 
-def _eval_pred_tile(cols_ref, col_idx, op, t1, t2, rounds):
-    """Evaluate one predicate on the whole (C, TILE) tile → bool(1, TILE).
+def _lanes(b):
+    """bool lanes → f32 0/1 lanes (the kernel's only mask currency)."""
+    return jnp.where(b, 1.0, 0.0)
 
-    ``col_idx``/``op``/... are dynamic scalars read from SMEM. The column is
-    selected with a dynamic sublane slice; the op dispatch is a scalar
-    switch, so only the selected branch's vector work executes (HASHMIX's
-    mix loop only runs for HASHMIX predicates — the cost heterogeneity the
-    ordering exploits is preserved on-chip).
+
+def _eval_pred(x, op, t1, t2, rounds):
+    """Evaluate one predicate on column values x f32[S, 128] → f32 0/1.
+
+    ``op``/``t1``/... are dynamic scalars read from SMEM. The op dispatch
+    is a scalar switch, so only the selected branch's vector work executes
+    (HASHMIX's mix loop only runs for HASHMIX predicates — the cost
+    heterogeneity the ordering exploits is preserved on-chip).
     """
-    x = pl.load(cols_ref, (pl.ds(col_idx, 1), slice(None)))  # f32[1, TILE]
-
     def _hashmix():
         def body(_, y):
             y = y * pred_lib.MIX_MUL + pred_lib.MIX_ADD
             return y - jnp.floor(y / pred_lib.MIX_MOD) * pred_lib.MIX_MOD
         mixed = jax.lax.fori_loop(0, jnp.maximum(rounds, 1), body, x)
-        return mixed > t1
+        return _lanes(mixed > t1)
 
     return jax.lax.switch(op, [
-        lambda: x > t1,
-        lambda: x < t1,
-        lambda: jnp.logical_and(x > t1, x < t2),
-        lambda: jnp.round(x) == jnp.round(t1),
+        lambda: _lanes(x > t1),
+        lambda: _lanes(x < t1),
+        lambda: _lanes(jnp.logical_and(x > t1, x < t2)),
+        lambda: _lanes(jnp.round(x) == jnp.round(t1)),
         _hashmix,
     ])
+
+
+def _lane_cumsum(m):
+    """Inclusive prefix sum along the lanes of each row of f32[S, 128]."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    shift = 1
+    while shift < LANES:
+        m = m + jnp.where(lane >= shift, pltpu.roll(m, shift, 1), 0.0)
+        shift *= 2
+    return m
+
+
+def _pack_rows(values, mask, fill):
+    """Pack each 128-lane row's survivors to the row's front, in order.
+
+    ``values``: list of f32[S, 128] columns; ``mask``: f32 0/1[S, 128].
+    Output lane j of a row takes the row's (j+1)-th survivor, found by a
+    binary search of the inclusive lane cumsum (the count of lanes whose
+    cumsum is ≤ j); lanes past the row's count hold ``fill``.
+    """
+    cs = _lane_cumsum(mask)
+    lane = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
+    lane_f = lane.astype(jnp.float32)
+    src = jnp.zeros(mask.shape, jnp.int32)
+    step = LANES // 2
+    while step:
+        probe = jnp.take_along_axis(cs, src + (step - 1), axis=1)
+        src = jnp.where(probe <= lane_f, src + step, src)
+        step //= 2
+    # lane j is a survivor slot iff its source's cumsum passes j (past the
+    # row's count the search saturates at lane 127, whose cumsum ≤ j)
+    keep = jnp.take_along_axis(cs, src, axis=1) > lane_f
+    return [jnp.where(keep, jnp.take_along_axis(v, src, axis=1), fill)
+            for v in values]
 
 
 def _kernel(# --- SMEM scalar/spec refs ---
             col_ref, op_ref, t1_ref, t2_ref, rounds_ref, perm_ref, group_ref,
             meta_ref,  # i32[4]: (n_rows, collect_rate, sample_phase, mode)
-            # --- skip-tier SMEM refs (skip=True), VMEM data, outputs ---
+            # --- skip-tier SMEM blocks (skip=True), VMEM data, outputs,
+            # scratch ---
             *refs,  # [pass_ref, fail_ref,] cols_ref, mask_ref, active_ref,
-                    # cut_ref, gcut_ref, nmon_ref [, packed_ref, cnt_ref]
+                    # cut_ref, gcut_ref, nmon_ref [, out_hbm, cnt_ref],
+                    # xs_ref [, stage_ref, buf_ref, sem, state_ref]
             n_preds: int, tile: int, groups: tuple, fill: float = 0.0,
-            skip: bool = False):
+            skip: bool = False, compact: bool = False, out_blocks: int = 0):
     if skip:
         pass_ref, fail_ref = refs[0], refs[1]
         refs = refs[2:]
     cols_ref, mask_ref, active_ref, cut_ref, gcut_ref, nmon_ref = refs[:6]
-    compact_refs = refs[6:]   # (packed_ref, cnt_ref) when compact=True
+    refs = refs[6:]
+    if compact:
+        out_hbm, cnt_ref, xs_ref, stage_ref, buf_ref, sem, state_ref = refs
+    else:
+        (xs_ref,) = refs
     t = pl.program_id(0)
     n_rows = meta_ref[0]
     collect_rate = meta_ref[1]
     sample_phase = meta_ref[2]
     block_mode = meta_ref[3]
     flat = len(set(groups)) == len(groups)   # static: all-singleton groups
+    n_cols = cols_ref.shape[0]
+    sub = tile // LANES
+    shape = (sub, LANES)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-    gidx = t * tile + lane
-    valid = gidx < n_rows                                    # bool(1, TILE)
+    # one relayout per tile: (C, TILE) → (C, TILE/128, 128) full vregs
+    xs_ref[...] = cols_ref[...].reshape(n_cols, sub, LANES)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    local = row * LANES + lane
+    gidx = t * tile + local
+    valid = _lanes(gidx < n_rows)                           # f32[S, 128]
 
     # ------------------------------------------------------ skip-tier lanes
     # Zone-map triage (skip-tier pre-pass) resolved this grid tile's
-    # STAT_TILE sub-tiles host-of-kernel; broadcast the i32 decisions from
-    # SMEM into lane masks. Decided sub-tiles start with no pending rows, so
-    # the existing ``alive > 0`` cond gives tile-granular skip for free — a
-    # fully decided grid tile evaluates ZERO predicates (with BlockSpec
-    # streaming the tile regardless; a Mosaic lowering would gate the DMA on
-    # the same SMEM scalars so failed tiles never enter VMEM column-wide).
-    pass_lane = None
+    # STAT_TILE sub-tiles (one per lane row); broadcast the i32 decisions
+    # from SMEM into lane masks. Decided sub-tiles start with no pending
+    # rows, so the existing ``alive > 0`` cond gives tile-granular skip for
+    # free — a fully decided grid tile evaluates ZERO predicates (with
+    # BlockSpec streaming the tile regardless; DMA-gating failed tiles is
+    # ROADMAP S2's open half).
     if skip:
-        sub = tile // STAT_TILE
-        segs_p, segs_f = [], []
+        pass_lane = jnp.zeros(shape, jnp.float32)
+        fail_lane = jnp.zeros(shape, jnp.float32)
         for j in range(sub):                 # static unroll: SMEM scalars
-            segs_p.append(jnp.full((1, STAT_TILE), pass_ref[t * sub + j],
-                                   jnp.int32))
-            segs_f.append(jnp.full((1, STAT_TILE), fail_ref[t * sub + j],
-                                   jnp.int32))
-        pass_lane = jnp.concatenate(segs_p, axis=1) > 0
-        fail_lane = jnp.concatenate(segs_f, axis=1) > 0
-        decided = jnp.logical_or(pass_lane, fail_lane)
+            pass_lane = jnp.where(row == j, pass_ref[0, j].astype(jnp.float32),
+                                  pass_lane)
+            fail_lane = jnp.where(row == j, fail_ref[0, j].astype(jnp.float32),
+                                  fail_lane)
+        pass_lane = jnp.minimum(pass_lane, 1.0)
+        decided = jnp.minimum(pass_lane + jnp.minimum(fail_lane, 1.0), 1.0)
 
     # ----------------------------------------------------------- chain lane
-    # survivors of closed groups; decided sub-tiles bypass the row level
-    mask = valid if not skip \
-        else jnp.logical_and(valid, jnp.logical_not(decided))
-    group_or = jnp.zeros((1, tile), bool)     # passes within the open group
+    # survivors of closed groups; decided sub-tiles bypass the row level.
+    # All masks are f32 0/1: AND = product, OR = max, and a dynamic group
+    # boundary flag b selects by arithmetic (x·(1-b) + y·b), never a select
+    # over bool vectors.
+    mask = valid if not skip else valid * (1.0 - decided)
+    group_or = None                          # passes within the open group
     for k in range(n_preds):                 # P static → unrolled on-chip
         pidx = perm_ref[k]
-        # group-boundary flags: static True when flat; dynamic SMEM scalar
+        # group-boundary flags: static when flat; dynamic SMEM scalar
         # comparisons otherwise (the permutation is data-dependent).
-        is_first = True if (flat or k == 0) \
-            else group_ref[perm_ref[k - 1]] != group_ref[pidx]
-        closes = True if (flat or k == n_preds - 1) \
-            else group_ref[perm_ref[k + 1]] != group_ref[pidx]
-        pending = mask if is_first is True \
-            else jnp.where(is_first, mask,
-                           jnp.logical_and(mask, jnp.logical_not(group_or)))
-        alive = jnp.sum(pending.astype(jnp.float32))
+        if flat or k == 0:
+            pending = mask
+        else:
+            first = jnp.where(group_ref[perm_ref[k - 1]] != group_ref[pidx],
+                              1.0, 0.0)
+            group_or = group_or * (1.0 - first)
+            pending = mask * (1.0 - group_or)
+        alive = jnp.sum(pending)
         active_ref[0, k] = alive
         res = jax.lax.cond(
             alive > 0.0,
-            lambda: _eval_pred_tile(cols_ref, col_ref[pidx], op_ref[pidx],
-                                    t1_ref[pidx], t2_ref[pidx],
-                                    rounds_ref[pidx]),
-            lambda: jnp.zeros((1, tile), bool),   # tile short-circuit
+            lambda: _eval_pred(xs_ref[col_ref[pidx]], op_ref[pidx],
+                               t1_ref[pidx], t2_ref[pidx], rounds_ref[pidx]),
+            lambda: jnp.zeros(shape, jnp.float32),   # tile short-circuit
         )
-        group_or = res if is_first is True \
-            else jnp.where(is_first, res, jnp.logical_or(group_or, res))
-        new_mask = jnp.logical_and(mask, group_or)
-        mask = new_mask if closes is True \
-            else jnp.where(closes, new_mask, mask)
+        group_or = res if (flat or k == 0) else jnp.maximum(group_or, res)
+        if flat or k == n_preds - 1:
+            mask = mask * group_or
+        else:
+            closes = jnp.where(group_ref[perm_ref[k + 1]] != group_ref[pidx],
+                               1.0, 0.0)
+            mask = mask * (1.0 - closes * (1.0 - group_or))
     if skip:
         # bulk-keep provably-passing sub-tiles (valid rows only — zero
-        # padding can satisfy a proof but never survives); the in-kernel
+        # padding can satisfy a proof but never survives); the fused
         # compaction below then bulk-copies them with no predicate work.
-        mask = jnp.logical_or(mask, jnp.logical_and(pass_lane, valid))
-    mask_ref[0, :] = mask[0].astype(jnp.int8)
+        mask = jnp.maximum(mask, pass_lane * valid)
+    mask_ref[...] = mask.astype(jnp.int32).astype(jnp.int8)
 
-    # ------------------------------------------------- in-kernel compaction
-    # The tile is still resident in VMEM: pack its survivors to the front of
-    # its own slot NOW, so the gather launch never re-reads the full batch.
-    # Slot = exclusive cumsum of the mask (O(TILE) scan, no sort); the
-    # non-survivors scatter into a dump lane that is sliced off. The zero
-    # (``fill``) tail is load-bearing: launch 2 relies on it when its
-    # overlapping stores stitch tiles together.
-    if compact_refs:
-        packed_ref, cnt_ref = compact_refs
-        mrow = mask[0]                                   # bool[TILE]
-        mi = mrow.astype(jnp.int32)
-        pos = jnp.cumsum(mi) - 1                         # within-tile slot
-        dest = jnp.where(mrow, pos, tile)
-        buf = jnp.full((cols_ref.shape[0], tile + 1), fill, cols_ref.dtype)
-        buf = buf.at[:, dest].set(cols_ref[:, :], mode="drop")
-        packed_ref[:, :] = buf[:, :tile]
-        cnt_ref[0, 0] = jnp.sum(mi)
+    # ------------------------------------------------------ fused compaction
+    if compact:
+        _compact_tile(t, mask, xs_ref, out_hbm, cnt_ref, stage_ref, buf_ref,
+                      sem, state_ref, tile=tile, fill=fill,
+                      out_blocks=out_blocks)
 
     # --------------------------------------------------------- monitor lane
     # row mode (paper-exact): deterministic stride over the GLOBAL row index
@@ -259,13 +299,14 @@ def _kernel(# --- SMEM scalar/spec refs ---
     # FRACTION delivered as one contiguous 128-lane slice of every
     # ``tile_stride``-th tile — scattered single rows cost a full vector op
     # each on a VPU, a contiguous slice costs one.
-    row_sampled = ((gidx + sample_phase) % collect_rate) == 0
-    tile_stride = jnp.maximum(collect_rate * 128 // tile, 1)
-    block_tile = ((t + sample_phase) % tile_stride) == 0
-    block_sampled = jnp.logical_and(block_tile, lane < 128)
-    sampled = jnp.logical_and(
-        jnp.where(block_mode == 1, block_sampled, row_sampled), valid)
-    n_sampled = jnp.sum(sampled.astype(jnp.float32))
+    row_sampled = _lanes(((gidx + sample_phase) % collect_rate) == 0)
+    tile_stride = jnp.maximum(collect_rate * LANES // tile, 1)
+    block_tile = jnp.where((t + sample_phase) % tile_stride == 0, 1.0, 0.0)
+    block_sampled = block_tile * _lanes(local < LANES)
+    use_block = jnp.where(block_mode == 1, 1.0, 0.0)
+    sampled = valid * (use_block * block_sampled
+                       + (1.0 - use_block) * row_sampled)
+    n_sampled = jnp.sum(sampled)
     nmon_ref[0, 0] = n_sampled
 
     members: list[list[int]] = [[] for _ in range(max(groups) + 1)]
@@ -276,18 +317,16 @@ def _kernel(# --- SMEM scalar/spec refs ---
     def _monitor():
         fails = []
         for p in range(n_preds):             # ALL predicates, user order
-            res = _eval_pred_tile(cols_ref, col_ref[p], op_ref[p],
-                                  t1_ref[p], t2_ref[p], rounds_ref[p])
-            fail = jnp.logical_not(res)
+            res = _eval_pred(xs_ref[col_ref[p]], op_ref[p], t1_ref[p],
+                             t2_ref[p], rounds_ref[p])
+            fail = 1.0 - res
             fails.append(fail)
-            cut_ref[0, p] = jnp.sum(
-                jnp.logical_and(sampled, fail).astype(jnp.float32))
+            cut_ref[0, p] = jnp.sum(sampled * fail)
         for gi, mem in enumerate(members):   # static group reduction
             gfail = fails[mem[0]]
             for m in mem[1:]:
-                gfail = jnp.logical_and(gfail, fails[m])
-            gcut_ref[0, gi] = jnp.sum(
-                jnp.logical_and(sampled, gfail).astype(jnp.float32))
+                gfail = gfail * fails[m]
+            gcut_ref[0, gi] = jnp.sum(sampled * gfail)
 
     @pl.when(n_sampled == 0.0)
     def _no_monitor():
@@ -297,10 +336,113 @@ def _kernel(# --- SMEM scalar/spec refs ---
             gcut_ref[0, gi] = 0.0
 
 
+def _compact_tile(t, mask, xs_ref, out_hbm, cnt_ref, stage_ref, buf_ref, sem,
+                  state_ref, *, tile: int, fill: float, out_blocks: int):
+    """Append this tile's survivors to the packed HBM output.
+
+    ``state_ref`` (SMEM i32[3], carried across the sequential grid):
+    survivors in the stage, TILE blocks already sent, DMA in flight.
+    ``stage_ref`` f32[rows, 2·TILE] holds < TILE survivors between steps,
+    so one tile's ≤ TILE more always fit; ``buf_ref`` is the DMA source.
+    Both have the output's ``dma_rows`` sublanes; rows past C are unused.
+    """
+    n_cols = xs_ref.shape[0]
+    sub = tile // LANES
+    last = pl.num_programs(0) - 1
+
+    def dma(block):
+        return pltpu.make_async_copy(
+            buf_ref, out_hbm.at[:, pl.ds(pl.multiple_of(block * tile, tile),
+                                         tile)], sem.at[0])
+
+    def wait_inflight():
+        @pl.when(state_ref[2] == 1)
+        def _():
+            dma(0).wait()
+            state_ref[2] = 0
+
+    @pl.when(t == 0)
+    def _init():
+        state_ref[0] = 0
+        state_ref[1] = 0
+        state_ref[2] = 0
+
+    packed = _pack_rows([xs_ref[c] for c in range(n_cols)], mask, fill)
+    total = jnp.sum(mask)
+    cnt_ref[0, 0] = total.astype(jnp.int32)
+
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    n = state_ref[0]
+    for r in range(sub):                     # stitch rows onto the stream
+        off = n % LANES
+        lo = pl.ds(pl.multiple_of(n - off, LANES), LANES)
+        hi = pl.ds(pl.multiple_of(n - off + LANES, LANES), LANES)
+        for c in range(n_cols):
+            rolled = pltpu.roll(packed[c][r:r + 1, :], off, 1)
+            stage_ref[c:c + 1, lo] = jnp.where(
+                lane >= off, rolled, stage_ref[c:c + 1, lo])
+            stage_ref[c:c + 1, hi] = rolled
+        n = n + jnp.sum(mask[r:r + 1, :]).astype(jnp.int32)
+    state_ref[0] = n
+
+    @pl.when(state_ref[0] >= tile)           # a full TILE of survivors
+    def _flush():
+        wait_inflight()
+        buf_ref[...] = stage_ref[:, :tile]
+        stage_ref[:, :tile] = stage_ref[:, tile:]
+        state_ref[0] = state_ref[0] - tile
+
+        @pl.when(state_ref[1] < out_blocks)  # saturated: drop survivors
+        def _send():
+            dma(state_ref[1]).start()
+            state_ref[2] = 1
+
+        state_ref[1] = state_ref[1] + 1
+
+    @pl.when(t == last)                      # partial stage + fill tail
+    def _finish():
+        wait_inflight()
+        lanes = jax.lax.broadcasted_iota(jnp.int32, buf_ref.shape, 1)
+        buf_ref[...] = jnp.where(lanes < state_ref[0], stage_ref[:, :tile],
+                                 fill)
+        sent = state_ref[1]
+
+        @pl.when(sent < out_blocks)
+        def _tail():
+            dma(sent).start()
+            dma(sent).wait()
+            buf_ref[...] = jnp.full(buf_ref.shape, fill, jnp.float32)
+
+            def start(b, carry):
+                dma(b).start()
+                return carry
+
+            def wait(b, carry):
+                dma(b).wait()
+                return carry
+
+            jax.lax.fori_loop(sent + 1, out_blocks, start, 0)
+            jax.lax.fori_loop(sent + 1, out_blocks, wait, 0)
+
+
+def dma_rows(n_cols: int) -> int:
+    """Sublane rows of the packed HBM output for ``n_cols`` columns.
+
+    Mosaic tiles a small f32 array's sublanes by 1, 2, 4 or 8 rows, and a
+    DMA may only move whole tiles; the output is padded to that tiling and
+    the wrapper slices the real columns back out.
+    """
+    for rows in (1, 2, 4):
+        if n_cols <= rows:
+            return rows
+    return -(-n_cols // 8) * 8
+
+
 def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
                         meta: jnp.ndarray, *, tile: int = DEFAULT_TILE,
                         interpret: bool = True, compact: bool = False,
-                        fill: float = 0.0, skip_decisions=None):
+                        capacity: int = 0, fill: float = 0.0,
+                        skip_decisions=None):
     """Launch the fused chain kernel.
 
     columns: f32[C, R_padded] with R_padded % tile == 0.
@@ -309,114 +451,74 @@ def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
     from the zone-map triage pre-pass — decided sub-tiles bypass the
     row-level chain (the monitor lane still samples them row-level, keeping
     ordering statistics identical with the tier on or off).
-    Returns (mask i8[1,Rp], active f32[n_tiles,P], cut f32[n_tiles,P],
-             gcut f32[n_tiles,G], nmon f32[n_tiles,1]); with
-    ``compact=True`` additionally (packed f32[C,Rp] — survivors packed to
-    the front of each tile's slot, ``fill`` tail — and cnt i32[n_tiles,1]).
+    Returns (mask i8[n_tiles, TILE/128, 128], active f32[n_tiles, 1, P],
+             cut f32[n_tiles, 1, P], gcut f32[n_tiles, 1, G],
+             nmon f32[n_tiles, 1, 1]); with ``compact=True`` additionally
+    (packed f32[C, capacity] — the survivors in row order, ``fill`` tail,
+    survivors past ``capacity`` dropped — and cnt i32[n_tiles, 1, 1]).
     """
     n_cols, n_rows_p = columns.shape
-    if n_rows_p % tile:
-        raise ValueError(f"padded rows {n_rows_p} not a multiple of tile {tile}")
+    if n_rows_p % tile or tile % LANES:
+        raise ValueError(f"padded rows {n_rows_p} not a multiple of tile "
+                         f"{tile} (a multiple of {LANES})")
+    if compact and capacity < 1:
+        raise ValueError(f"compact=True needs capacity >= 1, got {capacity}")
     n_tiles = n_rows_p // tile
+    sub = tile // LANES
     n_preds = int(specs.column.shape[0])
     groups = specs.groups                    # static tuple (pytree aux)
     n_groups = max(groups) + 1
     garr = jnp.asarray(groups, jnp.int32)
 
     smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
-    grid = (n_tiles,)
 
-    out_specs = [
-        pl.BlockSpec((1, tile), lambda i: (0, i)),
-        pl.BlockSpec((1, n_preds), lambda i: (i, 0)),
-        pl.BlockSpec((1, n_preds), lambda i: (i, 0)),
-        pl.BlockSpec((1, n_groups), lambda i: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i: (i, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((1, n_rows_p), jnp.int8),
-        jax.ShapeDtypeStruct((n_tiles, n_preds), jnp.float32),
-        jax.ShapeDtypeStruct((n_tiles, n_preds), jnp.float32),
-        jax.ShapeDtypeStruct((n_tiles, n_groups), jnp.float32),
-        jax.ShapeDtypeStruct((n_tiles, 1), jnp.float32),
-    ]
+    def counters(width, dtype=jnp.float32):
+        return (smem((None, 1, width), lambda i: (i, 0, 0)),
+                jax.ShapeDtypeStruct((n_tiles, 1, width), dtype))
+
+    outs = [(pl.BlockSpec((None, sub, LANES), lambda i: (i, 0, 0)),
+             jax.ShapeDtypeStruct((n_tiles, sub, LANES), jnp.int8)),
+            counters(n_preds), counters(n_preds), counters(n_groups),
+            counters(1)]
+    scratch = [pltpu.VMEM((n_cols, sub, LANES), jnp.float32)]
+    out_blocks = 0
     if compact:
-        out_specs += [pl.BlockSpec((n_cols, tile), lambda i: (0, i)),
-                      pl.BlockSpec((1, 1), lambda i: (i, 0))]
-        out_shape += [jax.ShapeDtypeStruct((n_cols, n_rows_p), jnp.float32),
-                      jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32)]
+        out_blocks = -(-capacity // tile)
+        rows = dma_rows(n_cols)
+        outs += [(pl.BlockSpec(memory_space=pl.ANY),
+                  jax.ShapeDtypeStruct((rows, out_blocks * tile),
+                                       jnp.float32)),
+                 counters(1, jnp.int32)]
+        scratch += [pltpu.VMEM((rows, 2 * tile), jnp.float32),
+                    pltpu.VMEM((rows, tile), jnp.float32),
+                    pltpu.SemaphoreType.DMA((1,)),
+                    pltpu.SMEM((3,), jnp.int32)]
 
     skip = skip_decisions is not None
     kernel = functools.partial(_kernel, n_preds=n_preds, tile=tile,
-                               groups=groups, fill=fill, skip=skip)
-    in_specs = [smem(), smem(), smem(), smem(), smem(), smem(), smem(),
-                smem()]
+                               groups=groups, fill=fill, skip=skip,
+                               compact=compact, out_blocks=out_blocks)
+    in_specs = [smem()] * 8
     args = [specs.column, specs.op, specs.t1, specs.t2, specs.rounds, perm,
             garr, meta]
     if skip:
-        in_specs += [smem(), smem()]
-        args += [skip_decisions[0], skip_decisions[1]]
+        in_specs += [smem((None, 1, sub), lambda i: (i, 0, 0))] * 2
+        args += [d.reshape(n_tiles, 1, sub) for d in skip_decisions]
     in_specs.append(pl.BlockSpec((n_cols, tile), lambda i: (0, i)))
     args.append(columns)
-    return pl.pallas_call(
+    res = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_tiles,),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=[o[0] for o in outs],
+        out_shape=[o[1] for o in outs],
+        scratch_shapes=scratch,
+        # compaction carries the stream position across grid steps
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary" if compact else "parallel",)),
         interpret=interpret,
         name="adaptive_filter_chain_skip" if skip else "adaptive_filter_chain",
     )(*args)
-
-
-def _gather_kernel(off_ref, packed_ref, out_ref, *, tile: int, capacity: int,
-                   fill: float):
-    """Second launch: stitch packed tiles at their global offsets.
-
-    The output block is the SAME [C, cap + TILE] window for every grid step
-    (revisited block). Step t stores its full packed tile at the dynamic
-    offset; because offsets advance by the previous tile's survivor count,
-    each store's ``fill`` tail is overwritten by the next tile's survivors —
-    the sequential TPU grid makes the overlap well-defined. Only survivor
-    bytes ever move twice.
-    """
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[:, :] = jnp.full(out_ref.shape, fill, out_ref.dtype)
-
-    off = off_ref[t]
-
-    @pl.when(off < capacity)                  # saturated: drop whole tile
-    def _store():
-        pl.store(out_ref, (slice(None), pl.ds(off, tile)), packed_ref[:, :])
-
-
-def compact_gather_pallas(packed_tiles: jnp.ndarray, offsets: jnp.ndarray,
-                          capacity: int, *, tile: int = DEFAULT_TILE,
-                          interpret: bool = True, fill: float = 0.0):
-    """Gather within-tile-packed survivors into one [C, capacity] buffer.
-
-    ``packed_tiles``: f32[C, Rp] from the chain launch (``compact=True``);
-    ``offsets``: i32[n_tiles] exclusive cumsum of the per-tile counts.
-    Reads only the packed tiles + the offset vector — the original columns
-    are not touched. Survivors beyond ``capacity`` are dropped (saturation
-    semantics identical to ``filter_exec.compact_fixed``).
-    """
-    n_cols, n_rows_p = packed_tiles.shape
-    n_tiles = n_rows_p // tile
-    kernel = functools.partial(_gather_kernel, tile=tile, capacity=capacity,
-                               fill=fill)
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_tiles,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((n_cols, tile), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((n_cols, capacity + tile), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_cols, capacity + tile),
-                                       jnp.float32),
-        interpret=interpret,
-        name="adaptive_filter_compact_gather",
-    )(offsets, packed_tiles)
-    return out[:, :capacity]
+    if compact:
+        res = (*res[:5], res[5][:n_cols, :capacity], res[6])
+    return res

@@ -4,9 +4,10 @@ Handles padding to tile multiples, packs the SMEM meta scalars, launches the
 kernel, and reduces per-tile counters into the framework-wide
 ``ChainResult`` contract shared with ``core.filter_exec`` (jnp path) and
 ``ref.py`` (oracle). ``filter_chain_compact`` additionally fuses survivor
-compaction into the same pass (in-kernel cumsum pack + offset-stitch gather
-launch — see ``filter_chain.py``). ``interpret=True`` on non-TPU backends,
-so the same call validates on CPU and runs compiled on TPU.
+compaction into the same pass (in-register row pack + stitched DMA to the
+packed HBM output — see ``filter_chain.py``). ``interpret=True`` on
+non-TPU backends, so the same call validates on CPU and runs compiled on
+TPU.
 """
 
 from __future__ import annotations
@@ -19,7 +20,6 @@ import jax.numpy as jnp
 from repro.core.engine.base import ChainResult, SkipInfo
 from repro.core.predicates import PredicateSpecs
 from repro.kernels.filter_chain.filter_chain import (DEFAULT_TILE, STAT_TILE,
-                                                     compact_gather_pallas,
                                                      filter_chain_pallas,
                                                      tile_stats_pallas)
 
@@ -38,7 +38,7 @@ def _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode):
 
 def _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm, n_rows,
                    skip: SkipInfo | None = None):
-    active_before = jnp.sum(active, axis=0)                  # f32[P]
+    active_before = jnp.sum(active[:, 0], axis=0)            # f32[P]
     cost_in_order = specs.static_cost[perm]
     work = jnp.sum(active_before * cost_in_order)
     n_monitored = jnp.sum(nmon)
@@ -50,13 +50,13 @@ def _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm, n_rows,
         n_fail_t = jnp.sum(skip.fail_tiles.astype(jnp.int32))
         n_amb_t = skip.pass_tiles.shape[0] - n_pass_t - n_fail_t
     return ChainResult(
-        mask=mask_i8[0, :n_rows].astype(bool),
+        mask=mask_i8.reshape(-1)[:n_rows].astype(bool),
         work_units=work,
         active_before=active_before,
-        cut_counts=jnp.sum(cut, axis=0),
+        cut_counts=jnp.sum(cut[:, 0], axis=0),
         n_monitored=n_monitored,
         monitor_cost=specs.static_cost * n_monitored,
-        group_cut_counts=jnp.sum(gcut, axis=0),
+        group_cut_counts=jnp.sum(gcut[:, 0], axis=0),
         n_tiles_pass=n_pass_t,
         n_tiles_fail=n_fail_t,
         n_tiles_ambiguous=n_amb_t,
@@ -142,16 +142,15 @@ def filter_chain_compact(columns: jnp.ndarray, specs: PredicateSpecs,
                          tile: int = DEFAULT_TILE, monitor_mode: str = "row",
                          fill: float = 0.0
                          ) -> tuple[ChainResult, jnp.ndarray, jnp.ndarray]:
-    """Fused chain + single-pass in-kernel compaction (two small launches).
+    """Fused chain + single-pass in-kernel compaction (one launch).
 
-    Returns (ChainResult, packed f32[C, capacity], n_kept i32[]). Launch 1
-    streams each tile HBM→VMEM exactly once and, while the tile is
-    resident, packs its survivors to the front of the tile's slot via the
-    exclusive mask cumsum (no ``argsort``); the only inter-launch work is an
-    O(n_tiles) exclusive cumsum of the per-tile survivor counts; launch 2
-    stitches the packed tiles at their global offsets, touching survivor
-    bytes only. Saturation semantics match ``filter_exec.compact_fixed``:
-    survivors beyond ``capacity`` are dropped and ``n_kept`` saturates.
+    Returns (ChainResult, packed f32[C, capacity], n_kept i32[]). The
+    launch streams each tile HBM→VMEM exactly once and, while the tile is
+    resident, packs its survivors (no ``argsort``) and appends them to the
+    packed HBM output at the running survivor count — the columns are never
+    read a second time. Saturation semantics match
+    ``filter_exec.compact_fixed``: survivors beyond ``capacity`` are
+    dropped and ``n_kept`` saturates.
     """
     if monitor_mode not in ("row", "block"):
         raise ValueError(monitor_mode)
@@ -160,20 +159,12 @@ def filter_chain_compact(columns: jnp.ndarray, specs: PredicateSpecs,
     if pad:
         columns = jnp.pad(columns, ((0, 0), (0, pad)))
     meta = _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode)
-    interpret = _should_interpret()
 
-    mask_i8, active, cut, gcut, nmon, packed_tiles, tile_cnt = \
+    mask_i8, active, cut, gcut, nmon, packed, tile_cnt = \
         filter_chain_pallas(columns, specs, perm.astype(jnp.int32), meta,
-                            tile=tile, interpret=interpret, compact=True,
-                            fill=fill)
-
-    cnt = tile_cnt[:, 0]                                     # i32[T]
-    csum = jnp.cumsum(cnt)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), csum.dtype), csum[:-1]]).astype(jnp.int32)
-    packed = compact_gather_pallas(packed_tiles, offsets, capacity,
-                                   tile=tile, interpret=interpret, fill=fill)
-    n_kept = jnp.minimum(csum[-1], capacity).astype(jnp.int32)
+                            tile=tile, interpret=_should_interpret(),
+                            compact=True, capacity=capacity, fill=fill)
+    n_kept = jnp.minimum(jnp.sum(tile_cnt), capacity).astype(jnp.int32)
 
     result = _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm,
                             n_rows)
@@ -220,7 +211,7 @@ def filter_chain_compact_skip(columns: jnp.ndarray, specs: PredicateSpecs,
                                          jnp.ndarray]:
     """``filter_chain_compact`` behind the skip tier.
 
-    Provably-passing sub-tiles are bulk-copied by the same in-kernel cumsum
+    Provably-passing sub-tiles are bulk-copied by the same in-kernel
     pack (their mask lanes arrive pre-set, no predicate work); provably-
     failing sub-tiles contribute nothing to the pack. Saturation semantics
     are unchanged.
@@ -230,20 +221,13 @@ def filter_chain_compact_skip(columns: jnp.ndarray, specs: PredicateSpecs,
     n_rows = columns.shape[1]
     columns = _pad_cols(columns, tile)
     meta = _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode)
-    interpret = _should_interpret()
 
-    mask_i8, active, cut, gcut, nmon, packed_tiles, tile_cnt = \
+    mask_i8, active, cut, gcut, nmon, packed, tile_cnt = \
         filter_chain_pallas(columns, specs, perm.astype(jnp.int32), meta,
-                            tile=tile, interpret=interpret, compact=True,
-                            fill=fill, skip_decisions=_skip_decisions(skip))
-
-    cnt = tile_cnt[:, 0]                                     # i32[T]
-    csum = jnp.cumsum(cnt)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), csum.dtype), csum[:-1]]).astype(jnp.int32)
-    packed = compact_gather_pallas(packed_tiles, offsets, capacity,
-                                   tile=tile, interpret=interpret, fill=fill)
-    n_kept = jnp.minimum(csum[-1], capacity).astype(jnp.int32)
+                            tile=tile, interpret=_should_interpret(),
+                            compact=True, capacity=capacity, fill=fill,
+                            skip_decisions=_skip_decisions(skip))
+    n_kept = jnp.minimum(jnp.sum(tile_cnt), capacity).astype(jnp.int32)
 
     result = _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm,
                             n_rows, skip=skip)

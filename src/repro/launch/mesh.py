@@ -22,11 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, found {len(devices)} — "
             "dryrun.py must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older jax: no devices kwarg
-        import numpy as np
-        return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):

@@ -27,6 +27,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FilterPlan, OrderingConfig, build_session
 from repro.data.stream import RequestStream
 from repro.runtime import GracefulShutdown, GuardedSession, GuardPolicy
@@ -156,6 +157,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--gate-p99-ms", type=float, default=2500.0,
                     help="smoke gate: maximum p99 admission latency")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     requests = args.requests if args.requests is not None \
         else (1536 if args.smoke else 4096)

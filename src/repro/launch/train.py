@@ -17,6 +17,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.configs.paper_filters import DEFAULT as PAPER
 from repro.core import (FilterPlan, OrderingConfig, TokenizeSpec,
@@ -135,6 +136,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)

@@ -144,7 +144,6 @@ def moe_ffn_ep(params, cfg, x, mesh):
     capacity dense path; equal in expectation).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     from repro.parallel import hints
 
     e = cfg.moe
@@ -232,7 +231,7 @@ def moe_ffn_ep(params, cfg, x, mesh):
     # y is replicated over the model axis by construction (each rank gets
     # its own tokens back from the reverse all_to_all) — the static VMA
     # checker can't see through the round-trip, hence check_vma=False.
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, tp_axis, None), P(None, None),
                   P(tp_axis, None, None), P(tp_axis, None, None),

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def pipeline_apply(block_fn, stage_params, x_microbatches, *, mesh,
@@ -70,16 +69,9 @@ def pipeline_apply(block_fn, stage_params, x_microbatches, *, mesh,
             return (shifted, outputs), None
 
         # initial carries must be marked stage-varying (they become so after
-        # one tick: stage_id enters the dataflow); old jax has no pcast and
-        # no varying-manifest axes — there the unmarked zeros are fine
-        # because the shard_map below disables the replication checker
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            buf0 = pcast(jnp.zeros_like(xs[0]), ("stage",), to="varying")
-            out0 = pcast(jnp.zeros_like(xs), ("stage",), to="varying")
-        else:
-            buf0 = jnp.zeros_like(xs[0])
-            out0 = jnp.zeros_like(xs)
+        # one tick: stage_id enters the dataflow)
+        buf0 = jax.lax.pcast(jnp.zeros_like(xs[0]), ("stage",), to="varying")
+        out0 = jax.lax.pcast(jnp.zeros_like(xs), ("stage",), to="varying")
         (_, outputs), _ = jax.lax.scan(tick, (buf0, out0),
                                        jnp.arange(ticks))
         # replicate final-stage outputs to every stage
@@ -88,7 +80,7 @@ def pipeline_apply(block_fn, stage_params, x_microbatches, *, mesh,
         return outputs
 
     spec_params = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(spec_params, P()), out_specs=P(),
     )(stage_params, x_microbatches)

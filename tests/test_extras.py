@@ -86,14 +86,14 @@ def test_dryrun_artifacts_schema():
             assert r["loop_aware"]["unknown_trip_loops"] == 0
 
 
-def test_serve_driver_end_to_end():
+def test_serve_driver_end_to_end(tmp_path):
     env = {"PYTHONPATH": str(pathlib.Path(__file__).resolve().parents[1] / "src")}
     import os
     env.update({k: v for k, v in os.environ.items() if k != "PYTHONPATH"})
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen2.5-14b",
          "--smoke", "--requests", "8", "--batch", "4", "--prompt-len", "16",
-         "--new-tokens", "2"],
+         "--new-tokens", "2", "--bench-out", str(tmp_path / "serve.json")],
         capture_output=True, text=True, env=env, timeout=420)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "admitted=" in out.stdout

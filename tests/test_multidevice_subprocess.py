@@ -35,7 +35,7 @@ def test_per_shard_scope_has_no_collectives():
         import numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core import paper_filters_4, pack
         from repro.core.filter_exec import run_chain
         from repro.core.scope import Scope, reduce_stats
@@ -77,7 +77,7 @@ def test_sharded_filter_matches_single_device():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core import paper_filters_4, pack
         from repro.core.filter_exec import run_chain
         from repro.data.stream import gen_batch
@@ -140,7 +140,7 @@ def test_compressed_psum_grad_allreduce():
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.parallel.compression import (compressed_psum,
             init_error_feedback, int8_decompress)
 

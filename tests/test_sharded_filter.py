@@ -240,7 +240,7 @@ def test_per_shard_hlo_has_no_collectives():
         session = build_session(cent)
         cols = jnp.asarray(np.random.default_rng(0).uniform(
             -64, 64, (4, 4096 * 4)).astype(np.float32))
-        txt = session.compiled_step_text(session.init_state(), cols)
+        txt = session.compiled_step_texts(session.init_state(), cols)["step"]
         per_shard = FilterPlan(predicates=paper_filters_4("fig1"),
                                scope="per_shard", shards=4,
                                ordering=ordering)

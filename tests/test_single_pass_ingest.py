@@ -173,7 +173,7 @@ def test_deferred_per_step_hlo_has_no_collectives():
         import jax.numpy as jnp
         from repro.data.stream import gen_batch
         cols = jnp.asarray(gen_batch(0, 0, 0, 4096 * 4))
-        txt = session.compiled_step_text(session.init_state(), cols)
+        txt = session.compiled_step_texts(session.init_state(), cols)["step"]
         assert collectives_in(txt)
         deferred = FilterPlan(predicates=paper_filters_4("fig1"),
                               scope="centralized", shards=4,
