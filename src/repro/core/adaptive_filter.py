@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import ordering as ordering_lib
 from repro.core import predicates as pred_lib
 from repro.core.engine import MonitorSpec, get_engine
@@ -232,7 +233,9 @@ class AdaptiveFilter:
         if not getattr(self._step_engine, "skip_gathers", False):
             return 0
         n_tiles = -(-n_rows // skip_tier_lib.SKIP_TILE)
-        return skip_tier_lib.quantize_amb_cap(int(info.n_ambiguous), n_tiles)
+        with obs.sync("AdaptiveFilter.skip_amb_cap"):
+            n_amb = int(info.n_ambiguous)
+        return skip_tier_lib.quantize_amb_cap(n_amb, n_tiles)
 
     # ----------------------------------------------------------- jit'd step
     def _advance_state(self, state: OrderState, res, costs,
@@ -399,21 +402,23 @@ class AdaptiveFilter:
         """
         if self.config.compact_capacity != "auto":
             return
-        epoch = int(np.max(np.asarray(new_state.epoch)))
-        if epoch <= self._auto_cap_epoch:
-            return
-        self._auto_cap_epoch = epoch
-        stats = jax.tree.map(np.asarray, evidence_state.stats)
-        n_mon = np.maximum(np.asarray(stats.n_monitored, np.float64), 0.0)
-        if np.max(n_mon) <= 0.0:
-            return                      # no evidence — keep current width
-        gcut = np.asarray(stats.group_cut, np.float64)
-        sel = np.clip(1.0 - gcut / np.maximum(n_mon, 1.0)[..., None],
-                      0.0, 1.0)
-        pass_rate = float(np.max(np.prod(sel, axis=-1)))  # max over shards
-        want = pass_rate * n_rows * self.config.compact_slack
-        quant = int(np.ceil(want / CAPACITY_QUANTUM)) * CAPACITY_QUANTUM
-        self._auto_cap = int(np.clip(quant, CAPACITY_QUANTUM, n_rows))
+        with obs.sync("AdaptiveFilter.observe_for_capacity"):
+            epoch = int(np.max(np.asarray(new_state.epoch)))
+            if epoch <= self._auto_cap_epoch:
+                return
+            self._auto_cap_epoch = epoch
+            stats = jax.tree.map(np.asarray, evidence_state.stats)
+            n_mon = np.maximum(np.asarray(stats.n_monitored, np.float64),
+                               0.0)
+            if np.max(n_mon) <= 0.0:
+                return                  # no evidence — keep current width
+            gcut = np.asarray(stats.group_cut, np.float64)
+            sel = np.clip(1.0 - gcut / np.maximum(n_mon, 1.0)[..., None],
+                          0.0, 1.0)
+            pass_rate = float(np.max(np.prod(sel, axis=-1)))  # over shards
+            want = pass_rate * n_rows * self.config.compact_slack
+            quant = int(np.ceil(want / CAPACITY_QUANTUM)) * CAPACITY_QUANTUM
+            self._auto_cap = int(np.clip(quant, CAPACITY_QUANTUM, n_rows))
 
     # ------------------------------------------------------ deferred epochs
     @property
@@ -426,7 +431,8 @@ class AdaptiveFilter:
         """Host-side boundary check for the deferred-exchange driver."""
         if not self.exchange_deferred:
             return False
-        rows = int(np.max(np.asarray(state.rows_into_epoch)))
+        with obs.sync("AdaptiveFilter.exchange_due"):
+            rows = int(np.max(np.asarray(state.rows_into_epoch)))
         return rows >= self.config.ordering.calculate_rate
 
     def exchange_update(self, state: OrderState,

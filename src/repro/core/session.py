@@ -42,6 +42,7 @@ from typing import Any, NamedTuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import stats as stats_lib
 from repro.core.adaptive_filter import (AdaptiveFilter, AdaptiveFilterConfig,
                                         StepMetrics)
@@ -79,10 +80,11 @@ class StepResult(NamedTuple):
     # advance — downstream consumers skip the batch instead of folding
     # garbage into the stream (see repro.runtime.guard.GuardedSession)
     quarantined: bool = False
-    # host wall-clock seconds spent DRIVING this step (dispatch + the
-    # sanctioned triage/boundary syncs; no extra device round-trip). The
-    # admission server's per-step gate cost lives here per the ROADMAP
-    # seam rule — on StepResult, not a side API.
+    # host time in ``step``, ending at dispatch: the ``session.step``
+    # span's duration (the host→device copy, dispatch and the sanctioned
+    # triage/boundary syncs; no extra device round-trip). The admission
+    # server's per-step gate cost lives here per the ROADMAP seam rule —
+    # on StepResult, not a side API.
     gate_s: float | None = None
 
     # ------------------------------------------------------- host accessors
@@ -415,76 +417,98 @@ class FilterSession:
         """
         import time
 
+        import jax
         import jax.numpy as jnp
 
-        t_gate = time.perf_counter()
-        cols = jnp.asarray(batch, jnp.float32)
-        n_local = int(cols.shape[1]) // self.num_shards
-        f = self.filter
-        prev = state
-        packed = n_kept = tokens = n_tokens = None
-        cap = None
-        skip_mode = self._skip_step_mode()
-        auto = self.plan.skip_tier == "auto" and not self.sharded
-        if auto:
-            t0 = time.perf_counter()
-        info = None
-        if skip_mode != "off":
-            # the tier's one host sync: the triage result sizes the jnp
-            # gather width (quantized — bounded jit cache); the pallas
-            # engine predicates in-kernel and skips the sync entirely
-            info = f._jit_triage(cols, bloom=skip_mode == "zonemap+bloom")
-            amb_cap = f.skip_amb_cap(info, n_local)
-        if self.plan.compact:
-            cap = f.resolve_capacity(n_local)
-            if info is not None:
-                state, packed, n_kept, mask, metrics = f._jit_skip_compact(
-                    state, cols, info.pass_tiles, info.fail_tiles,
-                    amb_cap=amb_cap, capacity=cap)
-            else:
-                state, packed, n_kept, mask, metrics = f._jit_compact(
-                    state, cols, capacity=cap)
-            if self.plan.tokenize is not None:
-                if self.sharded:
-                    tokens, n_tokens = self._tokenize_sharded(packed, n_kept)
+        with obs.step_span("session.step") as root:
+            with obs.span("session.h2d"):
+                cols = jnp.asarray(batch, jnp.float32)
+            if not isinstance(batch, jax.Array):
+                obs.count("session.h2d_bytes", cols.nbytes)
+            n_local = int(cols.shape[1]) // self.num_shards
+            f = self.filter
+            prev = state
+            packed = n_kept = tokens = n_tokens = None
+            cap = None
+            skip_mode = self._skip_step_mode()
+            auto = self.plan.skip_tier == "auto" and not self.sharded
+            if auto:
+                t0 = time.perf_counter()
+            info = None
+            if skip_mode != "off":
+                # the tier's one host sync: the triage result sizes the jnp
+                # gather width (quantized — bounded jit cache); the pallas
+                # engine predicates in-kernel and skips the sync entirely
+                with obs.span("session.triage"):
+                    info = f._jit_triage(
+                        cols, bloom=skip_mode == "zonemap+bloom")
+                    amb_cap = f.skip_amb_cap(info, n_local)
+            with obs.span("session.dispatch"):
+                if self.plan.compact:
+                    cap = f.resolve_capacity(n_local)
+                    if info is not None:
+                        state, packed, n_kept, mask, metrics = \
+                            f._jit_skip_compact(
+                                state, cols, info.pass_tiles,
+                                info.fail_tiles, amb_cap=amb_cap,
+                                capacity=cap)
+                    else:
+                        state, packed, n_kept, mask, metrics = \
+                            f._jit_compact(state, cols, capacity=cap)
+                    if self.plan.tokenize is not None:
+                        if self.sharded:
+                            tokens, n_tokens = self._tokenize_sharded(
+                                packed, n_kept)
+                        else:
+                            from repro.data import tokenizer
+                            ts = self.plan.tokenize
+                            tokens, n_tokens = tokenizer.tokens_from_padded(
+                                packed, n_kept, ts.vocab_size,
+                                ts.tokens_per_row)
+                elif info is not None:
+                    state, mask, metrics = f._jit_skip(
+                        state, cols, info.pass_tiles, info.fail_tiles,
+                        amb_cap=amb_cap)
                 else:
-                    from repro.data import tokenizer
-                    ts = self.plan.tokenize
-                    tokens, n_tokens = tokenizer.tokens_from_padded(
-                        packed, n_kept, ts.vocab_size, ts.tokens_per_row)
-        elif info is not None:
-            state, mask, metrics = f._jit_skip(
-                state, cols, info.pass_tiles, info.fail_tiles,
-                amb_cap=amb_cap)
-        else:
-            state, mask, metrics = f.jit_step(state, cols)
-        if auto:
-            self._observe_skip_arm(skip_mode, mask, metrics, t0,
-                                   int(cols.shape[1]))
-        if self._core.exchange_deferred:
-            # host-counted boundary: no per-step device sync (the jitted
-            # exchange itself checks/derives everything it needs). One
-            # session drives one state stream; if the counter has drifted
-            # anyway (states advanced outside this session), the
-            # authoritative device check below self-heals it at the cost
-            # of one sync per presumed boundary.
-            self._rows_local += n_local
-            if self._rows_local >= self.plan.ordering.calculate_rate:
-                if f.exchange_due(state):
-                    state = f.maybe_exchange(state)
-                    self._rows_local %= self.plan.ordering.calculate_rate
-                else:
-                    self._rows_local = self._sync_rows_into_epoch(state)
-        f.observe_for_capacity(prev, state, n_local)
-        # a deferred exchange may have just fired the epoch boundary — the
-        # metrics must report the post-exchange epoch (one uniform answer)
-        metrics = metrics._replace(epoch=state.epoch)
+                    state, mask, metrics = f.jit_step(state, cols)
+            if auto:
+                self._observe_skip_arm(skip_mode, mask, metrics, t0,
+                                       int(cols.shape[1]))
+            if self._core.exchange_deferred:
+                with obs.span("session.exchange"):
+                    state = self._drive_boundary(state, n_local)
+            f.observe_for_capacity(prev, state, n_local)
+            # a deferred exchange may have just fired the epoch boundary —
+            # the metrics must report the post-exchange epoch (one uniform
+            # answer)
+            metrics = metrics._replace(epoch=state.epoch)
         # no host sync here — overflow accounting surfaces through the
         # StepResult accessors (which warn once per result), keeping the
         # hot step free of forced device round-trips
         return state, StepResult(mask, packed, n_kept, tokens, n_tokens,
                                  metrics, cap, warn_cell=[],
-                                 gate_s=time.perf_counter() - t_gate)
+                                 gate_s=root.seconds)
+
+    def _drive_boundary(self, state: OrderState, n_local: int) -> OrderState:
+        """The deferred epoch boundary, counted on the host: no per-step
+        device sync until the host counter says one may be due (the jitted
+        exchange itself checks/derives everything it needs). Then
+        ``maybe_exchange`` reads the device counter once and fires the
+        exchange if it is due. One session drives one state stream; if the
+        counter has drifted anyway (states advanced outside this session),
+        a presumed boundary that was not due re-anchors it from the
+        device."""
+        self._rows_local += n_local
+        rate = self.plan.ordering.calculate_rate
+        if self._rows_local < rate:
+            return state
+        new = self.filter.maybe_exchange(state)
+        if new is state:             # not due: the counter drifted
+            self._rows_local = self._sync_rows_into_epoch(state)
+            return state
+        obs.count("session.exchanges")
+        self._rows_local %= rate
+        return new
 
     # ------------------------------------------------- sanctioned host syncs
     # These two helpers are the session driver's ONLY deliberate
@@ -504,15 +528,16 @@ class FilterSession:
 
         import jax
 
-        jax.block_until_ready(mask)
-        dt = time.perf_counter() - t0
-        ambig_frac = None
-        if skip_mode != "off":
-            n_amb = float(np.sum(np.asarray(metrics.n_tiles_ambiguous)))
-            n_tot = n_amb \
-                + float(np.sum(np.asarray(metrics.n_tiles_pass))) \
-                + float(np.sum(np.asarray(metrics.n_tiles_fail)))
-            ambig_frac = n_amb / max(n_tot, 1.0)
+        with obs.sync("FilterSession._observe_skip_arm"):
+            jax.block_until_ready(mask)
+            dt = time.perf_counter() - t0
+            ambig_frac = None
+            if skip_mode != "off":
+                n_amb = float(np.sum(np.asarray(metrics.n_tiles_ambiguous)))
+                n_tot = n_amb \
+                    + float(np.sum(np.asarray(metrics.n_tiles_pass))) \
+                    + float(np.sum(np.asarray(metrics.n_tiles_fail)))
+                ambig_frac = n_amb / max(n_tot, 1.0)
         self._skip_tuner.observe(skip_mode, dt * 1e6 / max(n_rows, 1),
                                  ambig_frac)
 
@@ -520,7 +545,8 @@ class FilterSession:
         """Re-anchor the host boundary counter from the device state — one
         sync per presumed boundary, only when the counter drifted (states
         advanced outside this session)."""
-        return int(np.max(np.asarray(state.rows_into_epoch)))
+        with obs.sync("FilterSession._sync_rows_into_epoch"):
+            return int(np.max(np.asarray(state.rows_into_epoch)))
 
     def _tokenize_sharded(self, packed, counts):
         """Per-shard device tokenize+pack under shard_map.
@@ -569,7 +595,9 @@ class FilterSession:
             import jax
 
             self._jit_validate = jax.jit(self._invariants_fn())
-        return bool(np.asarray(self._jit_validate(state)))
+        ok = self._jit_validate(state)
+        with obs.sync("FilterSession.validate_state"):
+            return bool(np.asarray(ok))
 
     def _invariants_fn(self):
         """The fused invariant check ``validate_state`` jits (also traced

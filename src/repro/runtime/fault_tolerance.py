@@ -31,7 +31,6 @@ step-kill injector):
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Iterable
 
 import jax
@@ -304,7 +303,6 @@ class TrainDriver:
                 batch = next(it, None)
                 if batch is None:
                     return True  # stream exhausted
-                t0 = time.perf_counter()
                 self.injector.maybe_fail(self.step)
                 self.params, self.opt_state, metrics = self.step_fn(
                     self.params, self.opt_state, batch)
@@ -312,7 +310,6 @@ class TrainDriver:
                 self.step += 1
                 if self.step % self.ckpt_every == 0:
                     self.save()
-                _ = time.perf_counter() - t0
         except RuntimeError:
             self.manager.wait()
             return False
