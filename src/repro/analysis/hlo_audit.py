@@ -130,8 +130,8 @@ def audit_plan(plan, mesh=None, *, rows_per_shard: int = 512,
                 f"deferred exchange on a {shards}-shard mesh compiled "
                 "without any collective — shard statistics are never "
                 "merged and every shard re-ranks on local evidence only",
-                "the exchange_update psum was dropped; check "
-                "reduce_stats wiring under shard_map"))
+                "the exchange_update psum was dropped; check its "
+                "axis names under shard_map"))
 
     if plan.tokenize is not None:
         diags += _audit_tokenizer(plan, rows_per_shard, shards)

@@ -18,11 +18,12 @@ from the jitted-step roots, and flags inside that reachable set:
   * ``enable_x64`` / ``jax_enable_x64`` anywhere in the reachable set
     (flipping x64 recompiles the world and breaks the u32-limb contract)
 
-Two syncs are SANCTIONED by design and allowlisted with their reasons:
-the skip tier's ambiguous-tile count (sizes a static gather width) and
-the deferred-exchange boundary row counter (drives epoch cadence). The
-allowlist is qualname-keyed; adding an entry is a reviewed diff, not a
-comment.
+A few syncs are SANCTIONED by design and allowlisted with their reasons:
+the skip tier's ambiguous-tile count (sizes a static gather width), the
+deferred-exchange counter's re-anchor on a state the session did not
+issue (the steady step counts boundaries on the host and reads nothing),
+and the guarded runtime's probes. The allowlist is qualname-keyed; adding
+an entry is a reviewed diff, not a comment.
 
 The call graph is deliberately over-approximate (any call to a name
 ``foo`` may reach ANY indexed function named ``foo``, attribute calls
@@ -74,9 +75,6 @@ ALLOWLIST: dict[str, str] = {
     "AdaptiveFilter.skip_amb_cap":
         "THE skip-tier sync: the ambiguous-tile count sizes a static "
         "(quantized) gather width — one int per step, by design",
-    "AdaptiveFilter.exchange_due":
-        "THE deferred-exchange sync: the boundary row counter decides "
-        "epoch cadence — one int per presumed boundary, by design",
     "AdaptiveFilter.observe_for_capacity":
         "epoch-boundary auto-capacity retune; reads accumulated stats "
         "only when an epoch just closed, never in the steady step",
@@ -88,8 +86,9 @@ ALLOWLIST: dict[str, str] = {
         "skip_tier='auto' tuner observation: block_until_ready gives "
         "honest per-arm wall clock — both arms pay the same sync",
     "FilterSession._sync_rows_into_epoch":
-        "deferred-boundary self-heal: one sync per presumed boundary "
-        "when the host row counter drifted (states advanced elsewhere)",
+        "deferred-boundary self-heal: one sync when step is handed a "
+        "state the session did not issue (a retry, a restored or "
+        "foreign state) — never in the steady step",
     "FilterSession.validate_state":
         "THE guarded-runtime integrity probe: every state invariant "
         "fused into one jitted boolean — one sync per validation "

@@ -63,22 +63,34 @@ def drive_exchange(owner, state: OrderState) -> OrderState:
     """Shared deferred-exchange driver (host side).
 
     ``owner`` is an ``AdaptiveFilter`` or ``ShardedAdaptiveFilter`` — any
-    object with ``config.exchange``, ``exchange_due``, the two jitted
-    exchange callables, and a ``_pending_stats`` slot. One implementation so
-    the subtle deferred-async stash semantics (first boundary falls back to
-    the synchronous merge; stash is transient across restores) cannot drift
-    between the single and sharded drivers.
+    object with ``exchange_deferred``, ``config.exchange``, the jitted
+    ``jit_exchange(state, stash)`` and a ``_stash`` slot. The jitted
+    exchange decides on the device whether the boundary is due and returns
+    the state unchanged when it is not, so dispatching it costs no host
+    sync. One implementation so the deferred-async stash semantics (first
+    boundary falls back to the synchronous merge; stash is transient across
+    restores) cannot drift between the single and sharded drivers.
     """
-    if not owner.exchange_due(state):
+    if not owner.exchange_deferred:
         return state
-    if owner.config.exchange == "deferred-async" \
-            and owner._pending_stats is not None:
-        state, merged = owner.jit_exchange_with(state, owner._pending_stats)
-    else:
-        state, merged = owner.jit_exchange(state)
-    owner._pending_stats = merged \
-        if owner.config.exchange == "deferred-async" else None
+    state, stash = owner.jit_exchange(state, exchange_stash(owner, state))
+    if stash is not None:
+        owner._stash = stash
     return state
+
+
+def exchange_stash(owner, state: OrderState):
+    """The deferred-async stash ``owner`` dispatches its exchange with:
+    (merged stats of the last boundary, has-stash flag), on the device,
+    laid out like ``state.stats`` (a leading shard axis when sharded).
+    Starts empty, so the first boundary merges synchronously. None under
+    "deferred", which keeps no stash."""
+    if owner.config.exchange != "deferred-async":
+        return None
+    if owner._stash is None:
+        owner._stash = (jax.tree.map(jnp.zeros_like, state.stats),
+                        jnp.zeros_like(state.epoch, dtype=jnp.bool_))
+    return owner._stash
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,11 +174,11 @@ class AdaptiveFilter:
         self._jit_step_skip = None
         self._jit_step_skip_compact = None
         self._jit_exchange = None
-        self._jit_exchange_with = None
-        # deferred-async: merged stats from the previous boundary, applied
-        # one epoch late (host-held; transient across checkpoint restarts —
-        # the first post-restore boundary falls back to synchronous merge).
-        self._pending_stats: FilterStats | None = None
+        # deferred-async: (merged stats of the previous boundary, has-stash
+        # flag), applied one epoch late (device arrays held by the filter;
+        # transient across checkpoint restarts — the first post-restore
+        # boundary falls back to synchronous merge). See exchange_stash.
+        self._stash = None
         # auto-capacity: current quantized width + last epoch it was tuned
         self._auto_cap: int | None = None
         self._auto_cap_epoch = 0
@@ -427,52 +439,56 @@ class AdaptiveFilter:
         return (self.config.adaptive and self.config.exchange != "eager"
                 and self._scope is Scope.CENTRALIZED)
 
-    def exchange_due(self, state: OrderState) -> bool:
-        """Host-side boundary check for the deferred-exchange driver."""
-        if not self.exchange_deferred:
-            return False
-        with obs.sync("AdaptiveFilter.exchange_due"):
-            rows = int(np.max(np.asarray(state.rows_into_epoch)))
-        return rows >= self.config.ordering.calculate_rate
-
-    def exchange_update(self, state: OrderState,
-                        use_stats: FilterStats | None = None
-                        ) -> tuple[OrderState, FilterStats]:
-        """One epoch boundary: merge stats across the mesh, re-rank.
+    def exchange_update(self, state: OrderState, stash=None):
+        """One epoch boundary if due: merge stats across the mesh, re-rank.
 
         The ONLY collective of the deferred CENTRALIZED mode lives here —
-        one psum of (2P + G + 1) floats per ``calculate_rate`` rows, issued
-        from a separate jitted call so the per-step module compiles with no
-        all-reduce at all. Returns (new_state, merged_stats); with
-        ``use_stats`` the re-rank consumes those (one-epoch-stale) stats
-        instead while the freshly merged ones are returned for the next
-        boundary (deferred-async).
+        one psum of (2P + G + 1) floats and the due flag per dispatch,
+        issued from a separate jitted call so the per-step module compiles
+        with no all-reduce at all. ``due`` is decided on the device (some
+        shard's ``rows_into_epoch`` reached ``calculate_rate``; the psum
+        makes it uniform over shards) and a state that is not due comes
+        back unchanged, so the host never reads the counter.
+
+        ``stash`` (deferred-async; ``exchange_stash``): the re-rank
+        consumes the previous boundary's merged stats, one epoch stale,
+        once the stash holds them, and the freshly merged ones replace
+        them. Returns (new_state, new_stash); the stash is None under
+        "deferred".
         """
+        rate = self.config.ordering.calculate_rate
         merged = state.stats
+        # f32 like the stats, so one psum carries both in one all-reduce
+        n_due = (state.rows_into_epoch >= rate).astype(jnp.float32)
         if self.axis_names:
-            merged = reduce_stats(merged, Scope.CENTRALIZED, self.axis_names)
-        new_state = ordering_lib.boundary_update(
-            state, self.config.ordering, groups=self.groups,
-            stats_override=merged if use_stats is None else use_stats)
-        return new_state, merged
+            merged, n_due = jax.lax.psum((merged, n_due), self.axis_names)
+        due = n_due > 0
+        use = merged
+        if stash is not None:
+            pending, have = stash
+            use = jax.tree.map(lambda p, m: jnp.where(have, p, m),
+                               pending, merged)
+            stash = (jax.tree.map(lambda m, p: jnp.where(due, m, p),
+                                  merged, pending), have | due)
+        new_state = jax.lax.cond(
+            due,
+            lambda s: ordering_lib.boundary_update(
+                s, self.config.ordering, groups=self.groups,
+                stats_override=use),
+            lambda s: s, state)
+        return new_state, stash
 
     @property
     def jit_exchange(self):
         if self._jit_exchange is None:
-            self._jit_exchange = jax.jit(lambda s: self.exchange_update(s))
+            self._jit_exchange = jax.jit(self.exchange_update)
         return self._jit_exchange
-
-    @property
-    def jit_exchange_with(self):
-        if self._jit_exchange_with is None:
-            self._jit_exchange_with = jax.jit(
-                lambda s, st: self.exchange_update(s, st))
-        return self._jit_exchange_with
 
     def maybe_exchange(self, state: OrderState) -> OrderState:
         """Drive the deferred epoch boundary if one is due (host helper).
 
-        Eager mode / off-boundary: returns ``state`` unchanged. In
+        Eager mode / off-boundary: returns ``state``'s values unchanged;
+        the due test runs on the device, so this never waits for it. In
         "deferred-async" the merged stats are stashed and applied at the
         NEXT boundary (first boundary degenerates to the synchronous
         merge), overlapping the collective with an epoch of filter work.
@@ -516,6 +532,7 @@ class AdaptiveFilter:
         n_preds = len(self.predicates)
         state = self.init_state(xp=np)
         defer = self.exchange_deferred
+        stash = None           # deferred-async: the last boundary's stats
         for batch in batches:
             perm = state.perm if cfg.adaptive else np.arange(n_preds)
             monitor = MonitorSpec(collect_rate=cfg.ordering.collect_rate,
@@ -539,10 +556,15 @@ class AdaptiveFilter:
                 if defer and state.rows_into_epoch >= \
                         cfg.ordering.calculate_rate:
                     # no mesh on the host path: the "exchange" is the
-                    # identity merge — the boundary cadence still matches
-                    # the deferred device path.
+                    # identity merge — the boundary cadence, and
+                    # deferred-async's one-epoch-stale re-rank, still
+                    # match the deferred device path.
+                    merged = state.stats
                     state = ordering_lib.boundary_update(
-                        state, cfg.ordering, groups=self.groups, xp=np)
+                        state, cfg.ordering, groups=self.groups, xp=np,
+                        stats_override=stash)
+                    if cfg.exchange == "deferred-async":
+                        stash = merged
             else:
                 state = state._replace(
                     sample_phase=(state.sample_phase + batch.shape[1])

@@ -45,7 +45,7 @@ import numpy as np
 from repro import obs
 from repro.core import stats as stats_lib
 from repro.core.adaptive_filter import (AdaptiveFilter, AdaptiveFilterConfig,
-                                        StepMetrics)
+                                        StepMetrics, exchange_stash)
 from repro.core.ordering import OrderState
 from repro.core.plan import FilterPlan, TokenizeSpec
 from repro.core.sharded import ShardedAdaptiveFilter, data_mesh
@@ -324,11 +324,16 @@ class FilterSession:
         # guarded-runtime integrity probe (lazy jit of state_invariants)
         self._jit_validate = None
         # host-side mirror of rows_into_epoch for the deferred-exchange
-        # boundary check: rows per shard are deterministic (every step adds
-        # the static local batch width), so the due-test needs NO
-        # device→host sync in the hot loop; re-anchored by init_state /
-        # restore_state, reduced modulo calculate_rate at each boundary
+        # boundary: rows per shard are deterministic (every step adds the
+        # static local batch width), so the boundary fires with NO
+        # device→host sync in the hot loop. Exact for the state this
+        # session last issued (``_issued``: set by init_state,
+        # restore_state and step); any other state handed to ``step`` —
+        # a retry from a pre-step state, a state advanced elsewhere —
+        # re-anchors it from the device once. Reduced modulo
+        # calculate_rate at each boundary.
         self._rows_local = 0
+        self._issued = None
 
     # -------------------------------------------------------------- shape
     @property
@@ -373,7 +378,8 @@ class FilterSession:
     # -------------------------------------------------------------- state
     def init_state(self) -> OrderState:
         self._rows_local = 0
-        return self.filter.init_state()
+        self._issued = self.filter.init_state()
+        return self._issued
 
     # ------------------------------------------------------------ skip tier
     def _skip_step_mode(self) -> str:
@@ -421,6 +427,10 @@ class FilterSession:
         import jax.numpy as jnp
 
         with obs.step_span("session.step") as root:
+            deferred = self._core.exchange_deferred
+            if deferred:
+                rows = self._rows_local if state is self._issued \
+                    else self._sync_rows_into_epoch(state)
             with obs.span("session.h2d"):
                 cols = jnp.asarray(batch, jnp.float32)
             if not isinstance(batch, jax.Array):
@@ -474,9 +484,12 @@ class FilterSession:
             if auto:
                 self._observe_skip_arm(skip_mode, mask, metrics, t0,
                                        int(cols.shape[1]))
-            if self._core.exchange_deferred:
+            if deferred:
                 with obs.span("session.exchange"):
-                    state = self._drive_boundary(state, n_local)
+                    state, rows = self._drive_boundary(state, rows + n_local)
+                # committed only once the step went through: a step that
+                # raised leaves the counter with the state it was handed
+                self._rows_local, self._issued = rows, state
             f.observe_for_capacity(prev, state, n_local)
             # a deferred exchange may have just fired the epoch boundary —
             # the metrics must report the post-exchange epoch (one uniform
@@ -489,30 +502,24 @@ class FilterSession:
                                  metrics, cap, warn_cell=[],
                                  gate_s=root.seconds)
 
-    def _drive_boundary(self, state: OrderState, n_local: int) -> OrderState:
-        """The deferred epoch boundary, counted on the host: no per-step
-        device sync until the host counter says one may be due (the jitted
-        exchange itself checks/derives everything it needs). Then
-        ``maybe_exchange`` reads the device counter once and fires the
-        exchange if it is due. One session drives one state stream; if the
-        counter has drifted anyway (states advanced outside this session),
-        a presumed boundary that was not due re-anchors it from the
-        device."""
-        self._rows_local += n_local
+    def _drive_boundary(self, state: OrderState,
+                        rows: int) -> tuple[OrderState, int]:
+        """The deferred epoch boundary, fired from the host row counter:
+        ``rows`` is the device's ``rows_into_epoch`` after this step,
+        counted on the host, so the steady step never reads the device.
+        Returns the state (post-exchange when the counter reached
+        ``calculate_rate``) and the counter after it. The jitted exchange
+        decides ``due`` again on the device and keeps a state that is not
+        due unchanged."""
         rate = self.plan.ordering.calculate_rate
-        if self._rows_local < rate:
-            return state
-        new = self.filter.maybe_exchange(state)
-        if new is state:             # not due: the counter drifted
-            self._rows_local = self._sync_rows_into_epoch(state)
-            return state
+        if rows < rate:
+            return state, rows
         obs.count("session.exchanges")
-        self._rows_local %= rate
-        return new
+        return self.filter.maybe_exchange(state), rows % rate
 
     # ------------------------------------------------- sanctioned host syncs
     # These two helpers are the session driver's ONLY deliberate
-    # device→host syncs outside the skip-tier/boundary counters owned by
+    # device→host syncs outside the skip-tier counter owned by
     # AdaptiveFilter; each is allowlisted by qualname (with its reason) in
     # ``repro.analysis.hotpath_lint.ALLOWLIST`` — a new sync anywhere else
     # in the reachable step graph fails the hot-path lint.
@@ -543,8 +550,8 @@ class FilterSession:
 
     def _sync_rows_into_epoch(self, state: OrderState) -> int:
         """Re-anchor the host boundary counter from the device state — one
-        sync per presumed boundary, only when the counter drifted (states
-        advanced outside this session)."""
+        sync per step handed a state this session did not issue (a retry
+        from a pre-step state, a state advanced elsewhere)."""
         with obs.sync("FilterSession._sync_rows_into_epoch"):
             return int(np.max(np.asarray(state.rows_into_epoch)))
 
@@ -637,14 +644,14 @@ class FilterSession:
         f = self.filter
         state = self.init_state()
         out: dict = {}
+        stash = exchange_stash(f, state)
         if self.sharded:
             out["step"] = jax.make_jaxpr(f.sharded_step)(state, cols)
-            out["exchange"] = jax.make_jaxpr(
-                lambda s: f._sharded_exchange(s))(state)
+            out["exchange"] = jax.make_jaxpr(f._sharded_exchange)(state,
+                                                                   stash)
         else:
             out["step"] = jax.make_jaxpr(f.step)(state, cols)
-            out["exchange"] = jax.make_jaxpr(
-                lambda s: f.exchange_update(s))(state)
+            out["exchange"] = jax.make_jaxpr(f.exchange_update)(state, stash)
         if self.plan.compact:
             cap = f.resolve_capacity(n_local)
             if self.sharded:
@@ -720,8 +727,10 @@ class FilterSession:
         return texts
 
     def compiled_exchange_text(self, state: OrderState) -> str:
-        return self.filter.compiled_exchange_text(state) if self.sharded \
-            else self.filter.jit_exchange.lower(state).compile().as_text()
+        if self.sharded:
+            return self.filter.compiled_exchange_text(state)
+        return self.filter.jit_exchange.lower(
+            state, exchange_stash(self.filter, state)).compile().as_text()
 
     # =========================================================== checkpoints
     @property
@@ -836,6 +845,7 @@ class FilterSession:
         restored = fstate_from_arrays(arrays)
         # re-anchor the host-side deferred-boundary row counter
         self._rows_local = int(np.max(np.asarray(restored.rows_into_epoch)))
+        self._issued = restored
         return restored
 
 

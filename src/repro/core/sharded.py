@@ -53,7 +53,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.adaptive_filter import (AdaptiveFilter, AdaptiveFilterConfig,
-                                        drive_exchange)
+                                        drive_exchange, exchange_stash)
 from repro.core.ordering import OrderState
 from repro.core.plan import validate_combo
 from repro.core.predicates import Predicate
@@ -124,8 +124,7 @@ class ShardedAdaptiveFilter:
         self._jit_step = None
         self._jit_step_compact = None
         self._jit_exchange = None
-        self._jit_exchange_with = None
-        self._pending_stats = None   # deferred-async: last boundary's merge
+        self._stash = None   # deferred-async: last boundary's merge (+flag)
 
     # ---------------------------------------------------------------- state
     def init_state(self) -> OrderState:
@@ -191,46 +190,35 @@ class ShardedAdaptiveFilter:
         return self._jit_step_compact
 
     # ------------------------------------------------------ deferred epochs
-    def _sharded_exchange(self, state: OrderState, use_stats=None):
+    def _sharded_exchange(self, state: OrderState, stash=None):
         """Shard_mapped ``AdaptiveFilter.exchange_update``: the deferred
-        mode's single per-epoch collective (psum inside the shard_map body),
-        returning (new_state [S,...], merged_stats [S,...] — every shard row
-        holds the identical global sums)."""
+        mode's single per-epoch collective (psum inside the shard_map body,
+        the due flag riding it), returning (new_state [S,...], new_stash —
+        every shard row of its stats holds the identical global sums; None
+        under "deferred")."""
 
-        def local(st, *maybe):
-            st = shard_slice(st, 0)
-            us = shard_slice(maybe[0], 0) if maybe else None
-            new_st, merged = self.inner.exchange_update(st, us)
-            return (jax.tree.map(lambda x: x[None], new_st),
-                    jax.tree.map(lambda x: x[None], merged))
+        def local(st, sh):                # sh: None under "deferred"
+            new = self.inner.exchange_update(shard_slice(st, 0),
+                                             shard_slice(sh, 0))
+            return jax.tree.map(lambda x: x[None], new)
 
         a = self.axis_name
-        n_in = 1 if use_stats is None else 2
-        in_specs = (P(a),) * n_in
-        out_specs = (P(a), P(a))
-        args = (state,) if use_stats is None else (state, use_stats)
-        return shard_map(local, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs)(*args)
+        return shard_map(local, mesh=self.mesh, in_specs=(P(a), P(a)),
+                         out_specs=P(a))(state, stash)
 
     @property
     def jit_exchange(self):
         if self._jit_exchange is None:
-            self._jit_exchange = jax.jit(lambda s: self._sharded_exchange(s))
+            self._jit_exchange = jax.jit(self._sharded_exchange)
         return self._jit_exchange
 
     @property
-    def jit_exchange_with(self):
-        if self._jit_exchange_with is None:
-            self._jit_exchange_with = jax.jit(
-                lambda s, st: self._sharded_exchange(s, st))
-        return self._jit_exchange_with
-
-    def exchange_due(self, state: OrderState) -> bool:
-        return self.inner.exchange_due(state)
+    def exchange_deferred(self) -> bool:
+        return self.inner.exchange_deferred
 
     def maybe_exchange(self, state: OrderState) -> OrderState:
         """Drive the deferred epoch boundary if due (host helper; the shared
-        driver with the shard_mapped exchange callables)."""
+        driver with the shard_mapped exchange callable)."""
         return drive_exchange(self, state)
 
     # -------------------------------------------------- capacity auto-tune
@@ -251,6 +239,8 @@ class ShardedAdaptiveFilter:
             state, columns).compile().as_text()
 
     def compiled_exchange_text(self, state: OrderState) -> str:
-        """Compiled HLO of the boundary exchange — deferred CENTRALIZED must
-        show its one all-reduce HERE and only here."""
-        return self.jit_exchange.lower(state).compile().as_text()
+        """Compiled HLO of the boundary exchange the driver dispatches —
+        deferred CENTRALIZED must show its one all-reduce HERE and only
+        here."""
+        return self.jit_exchange.lower(
+            state, exchange_stash(self, state)).compile().as_text()

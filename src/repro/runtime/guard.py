@@ -407,10 +407,11 @@ class GuardedSession:
         new_plan = dataclasses.replace(old.plan, **changes)
         mesh = old.filter.mesh if old.sharded else None
         new = FilterSession(new_plan, mesh=mesh)
-        # the host-side deferred-boundary row counter survives the swap
-        # (plan fingerprints exclude every changed field, so the live
-        # OrderState and all ring blobs remain loadable as-is)
-        new._rows_local = old._rows_local
+        # the host-side deferred-boundary row counter, and the state it
+        # counts for, survive the swap (plan fingerprints exclude every
+        # changed field, so the live OrderState and all ring blobs remain
+        # loadable as-is)
+        new._rows_local, new._issued = old._rows_local, old._issued
         self.session = new
         return {"step": self._step_idx, "reason": reason,
                 "changes": {k: str(v) for k, v in changes.items()}}

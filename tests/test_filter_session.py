@@ -549,9 +549,15 @@ def test_elastic_restore_rederives_same_perm(s_old, s_new):
             want = np.asarray(getattr(st.stats, k)).sum(axis=0)
             assert np.array_equal(got, want), (k, got, want)
         # the boundary exchange re-derives the SAME permutation on both
-        # meshes — the machine-checkable "sums are associative" claim
-        na, _ = a.filter.jit_exchange(st)
-        nb, _ = b.filter.jit_exchange(st2)
+        # meshes — the machine-checkable "sums are associative" claim. The
+        # exchange fires only when due: a full epoch more of rows makes it
+        # due and leaves the overshoot it keeps as it was
+        due = lambda s: s._replace(
+            rows_into_epoch=s.rows_into_epoch + ordering.calculate_rate)
+        na, _ = a.filter.jit_exchange(due(st))
+        nb, _ = b.filter.jit_exchange(due(st2))
+        assert (np.asarray(na.epoch) == np.asarray(st.epoch) + 1).all()
+        assert (np.asarray(nb.epoch) == np.asarray(st.epoch).max() + 1).all()
         pa, pb = np.asarray(na.perm), np.asarray(nb.perm)
         assert len({{tuple(p) for p in pa}} | {{tuple(p) for p in pb}}) == 1, \\
             (pa, pb)

@@ -71,6 +71,38 @@ def test_per_shard_scope_has_no_collectives():
     assert "SCOPE-OK" in out
 
 
+def test_deferred_exchange_module_holds_one_all_reduce():
+    """The deferred exchange decides on the device whether the boundary is
+    due: the flag rides the stats' psum, so on four devices the exchange
+    module still holds exactly one all-reduce and the per-step module
+    none, in both deferred modes."""
+    out = run_py("""
+        import re
+        import jax.numpy as jnp
+        from repro.core import (FilterPlan, OrderingConfig, build_session,
+                                paper_filters_4)
+        from repro.data.stream import gen_batch
+
+        cols = jnp.asarray(gen_batch(0, 0, 0, 4 * 4096))
+        for exchange in ("deferred", "deferred-async"):
+            s = build_session(FilterPlan(
+                predicates=paper_filters_4("fig1"), scope="centralized",
+                shards=4, exchange=exchange,
+                ordering=OrderingConfig(collect_rate=10,
+                                        calculate_rate=2000)))
+            st = s.init_state()
+            ops = lambda txt: re.findall(
+                r" (all-reduce|all-gather|reduce-scatter|all-to-all"
+                r"|collective-permute)(?:-start)?\\(", txt)
+            ex = ops(s.compiled_exchange_text(st))
+            assert ex == ["all-reduce"], (exchange, ex)
+            step = ops(s.compiled_step_texts(st, cols)["step"])
+            assert step == [], (exchange, step)
+        print("ONE-AR-OK")
+    """)
+    assert "ONE-AR-OK" in out
+
+
 def test_sharded_filter_matches_single_device():
     """Filter outcome and monitor stats are identical whether the batch is
     processed on 1 device or sharded 4 ways (per-shard states merged)."""

@@ -77,7 +77,7 @@ def _warm(session, n: int = 2):
 def test_span_is_the_shared_noop_without_a_profiler():
     obs.clear()
     assert obs.span("session.x") is obs.NOOP
-    assert obs.sync("AdaptiveFilter.exchange_due") is obs.NOOP
+    assert obs.sync("FilterSession._sync_rows_into_epoch") is obs.NOOP
     obs.count("session.x")
     session = _session()
     _, results = _steps(session, 3)
@@ -155,23 +155,21 @@ def test_trace_file_holds_the_same_spans_on_the_host_plane(stream_trace):
                                                                  dur_ns)
 
 
-def test_deferred_centralized_syncs_once_per_step(tmp_path):
-    """Batches wider than calculate_rate: every step passes a presumed
-    boundary, so ``exchange_due`` reads the device counter once per step
-    and the exchange fires."""
+def test_deferred_centralized_steady_step_never_syncs(tmp_path):
+    """Batches wider than calculate_rate: every step crosses a boundary.
+    The host row counter fires each exchange and the device decides it is
+    due, so the steady step reads nothing back and still exchanges once
+    per step."""
     session = _session(scope="centralized", exchange="deferred",
                        calculate_rate=ROWS // 2)
-    _warm(session)
+    state, _ = _steps(session, 2)                # compile outside the trace
     with _Traced(tmp_path) as t:
-        _steps(session, 3)
-    syncs = t.spans("session.sync")
-    assert [s.meta["site"] for s in syncs] == \
-        ["AdaptiveFilter.exchange_due"] * 3
-    assert sorted(s.step for s in syncs) == \
-        sorted(r.step for r in t.spans("session.step"))
-    assert all(s.parent == "session.exchange" for s in syncs)
-    assert t.snap.counts["session.syncs"] == 3
+        _, results = _steps(session, 3, state)
+    assert t.spans("session.sync") == []
+    assert "session.syncs" not in t.snap.counts
+    assert len(t.spans("session.exchange")) == 3
     assert t.snap.counts["session.exchanges"] == 3
+    assert [r.metrics_dict()["epoch"] for r in results] == [3, 4, 5]
 
 
 def test_per_shard_session_never_syncs(tmp_path):
@@ -211,8 +209,8 @@ def test_bounded_record_counts_drops(tmp_path, monkeypatch):
 # ------------------------------------------- the allowlist and the spans
 def _retried_step(session):
     """A step retried from its pre-step state (the guarded runtime's
-    rollback does this): the host boundary counter runs ahead of the
-    device's, so a presumed boundary is not due and is re-anchored."""
+    rollback does this): the session did not issue the state it is handed
+    for the retry, so it re-anchors its boundary counter from the device."""
     state = session.init_state()
     for k in range(4):
         nxt, _ = session.step(state, _batch(k))
@@ -220,10 +218,47 @@ def _retried_step(session):
         state = nxt
 
 
+def _detour_steps(session, detour, n: int = 12, at: int = 6,
+                  back: int = 3):
+    """(epoch, perm) of each of ``n`` steps the stream keeps, with one
+    detour at step ``at``: "retry" steps the pre-step state once more
+    first (an attempt whose result was dropped), "rollback" hands back the
+    state issued ``back`` steps earlier and replays from there, "restore"
+    restores the checkpoint saved there and replays."""
+    state = session.init_state()
+    states, blobs, kept = [], [], []
+    k = 0
+    while k < n:
+        states[k:] = [state]
+        blobs[k:] = [session.save_state(state)]
+        if k == at and detour == "retry":
+            session.step(state, _batch(k))
+        elif k == at and detour is not None:
+            k -= back
+            del kept[k:]
+            state = states[k] if detour == "rollback" \
+                else session.restore_state(blobs[k])
+            detour = None
+        state, res = session.step(state, _batch(k))
+        m = res.metrics_dict()
+        kept.append((m["epoch"], m["perm"]))
+        k += 1
+    return kept
+
+
+@pytest.mark.parametrize("detour", ["retry", "rollback", "restore"])
+def test_detoured_stream_keeps_the_epochs(detour):
+    """A retried step, a rollback to an older state and a restored
+    checkpoint each hand ``step`` a state the host counter does not count
+    for; the epochs and orders then match a run without the detour."""
+    plan = dict(scope="centralized", exchange="deferred",
+                calculate_rate=3 * ROWS + ROWS // 2)
+    want = _detour_steps(_session(**plan), None)
+    assert want[-1][0] >= 3
+    assert _detour_steps(_session(**plan), detour) == want
+
+
 SYNC_SITES = {
-    "AdaptiveFilter.exchange_due": (
-        dict(scope="centralized", exchange="deferred",
-             calculate_rate=ROWS // 2), lambda s: _steps(s, 2)),
     "AdaptiveFilter.skip_amb_cap": (
         dict(skip_tier="zonemap"), lambda s: _steps(s, 2)),
     "AdaptiveFilter.observe_for_capacity": (

@@ -131,6 +131,83 @@ def test_deferred_async_lags_at_most_one_epoch():
     assert async_[-1][0] == eager[-1][0] > 0
 
 
+EXCHANGE_MODES = ["deferred", "deferred-async"]
+
+
+@pytest.mark.parametrize("exchange", EXCHANGE_MODES)
+def test_session_exchange_matches_host_path(exchange):
+    """The session fires each boundary from its host row counter and the
+    device re-checks it; with a rate that is no multiple of the batch
+    width (boundaries on some steps, not others) the epochs and adopted
+    orders equal the numpy host path's, drift and all."""
+    import jax
+
+    from repro.core import (AdaptiveFilter, AdaptiveFilterConfig,
+                            FilterPlan, OrderingConfig, build_session,
+                            paper_filters_4)
+    from repro.data.stream import DriftConfig, gen_batch
+
+    rows = 2048
+    ordering = OrderingConfig(collect_rate=50, calculate_rate=5000)
+    drift = DriftConfig(kind="regime", period_rows=6000)
+    batches = [gen_batch(3, b, b * rows, rows, drift) for b in range(14)]
+    session = build_session(
+        FilterPlan(predicates=paper_filters_4("fig1"), ordering=ordering,
+                   scope="centralized", exchange=exchange),
+        mesh=jax.make_mesh((1,), ("data",)))
+    state = session.init_state()
+    got = []
+    for batch in batches:
+        state, res = session.step(state, batch)
+        m = res.metrics_dict()
+        got.append((m["epoch"], tuple(m["perm"][0])))
+    host = AdaptiveFilter(paper_filters_4("fig1"), AdaptiveFilterConfig(
+        ordering=ordering, scope="centralized", exchange=exchange,
+        backend="numpy"))
+    want = [(m["epoch"], tuple(m["perm"]))
+            for _, _, m in host._process_stream_host(batches)]
+    assert got == want
+    epochs = [e for e, _ in got]
+    assert epochs[-1] >= 4
+    assert any(a == b for a, b in zip(epochs, epochs[1:]))   # no boundary
+    assert len({p for _, p in got}) > 2                      # re-ranked
+
+
+@pytest.mark.parametrize("exchange", EXCHANGE_MODES)
+def test_maybe_exchange_off_boundary_keeps_the_state(exchange):
+    """A direct ``maybe_exchange`` on a state that is not due dispatches
+    the exchange and gets every value back unchanged — epoch, perm,
+    accumulators and the deferred-async stash."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import (AdaptiveFilterConfig, OrderingConfig,
+                            ShardedAdaptiveFilter, paper_filters_4)
+    from repro.data.stream import gen_batch
+
+    sf = ShardedAdaptiveFilter(paper_filters_4("fig1"), AdaptiveFilterConfig(
+        scope="centralized", exchange=exchange,
+        ordering=OrderingConfig(collect_rate=50, calculate_rate=5000)),
+        mesh=jax.make_mesh((1,), ("data",)))
+    st = sf.init_state()
+    for b in range(3):                      # 6144 rows: the first boundary
+        st, _, _ = sf.jit_step(st, jnp.asarray(gen_batch(0, b, b * 2048,
+                                                         2048)))
+    st = sf.maybe_exchange(st)
+    assert int(np.asarray(st.epoch)[0]) == 1
+    st, _, _ = sf.jit_step(st, jnp.asarray(gen_batch(0, 3, 6144, 2048)))
+    assert int(np.asarray(st.rows_into_epoch)[0]) < 5000
+    stash = sf._stash
+    assert (stash is None) == (exchange == "deferred")
+    after = sf.maybe_exchange(st)
+    for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(after)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    if stash is not None:
+        assert bool(np.asarray(stash[1])[0])
+        for a, b in zip(jax.tree.leaves(stash), jax.tree.leaves(sf._stash)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_exchange_config_validation():
     from repro.core import AdaptiveFilterConfig
 
