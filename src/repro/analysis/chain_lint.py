@@ -22,6 +22,14 @@ linter↔resolver cross-check in tests/test_analysis.py):
             open (the half-even tie at the endpoints falls between)
   HASHMIX   opaque (the mix destroys ordering): over-approx is the whole
             line, under-approx is empty — it never participates in proofs.
+  IN        round(x) in a code set: one code lints exactly like EQ on it;
+            more codes over-approximate by [min - 0.5, max + 0.5] and
+            under-approximate by ∅; no code at all is unsatisfiable.
+
+Trees (AND-terms inside OR-groups) lint at the term level: a term is dead
+when one of its atoms is, a group when all its terms are, and OR-level
+containment and always-true members are judged on one-atom terms (or, for
+always-true, on terms whose every atom is).
 
 Every check uses the approximation in the sound direction: emptiness /
 unsatisfiability intersects OVER-approximations (superset ∩ superset = ∅
@@ -130,8 +138,25 @@ def _f32(x: float) -> float:
     return float(np.float32(x))
 
 
+def _in_as_eq(p: Predicate) -> Predicate | None:
+    """A one-code OP_IN as the EQ it lints like (None otherwise)."""
+    codes = pred_lib.in_codes(p.t1, p.t2)
+    if len(codes) != 1:
+        return None
+    return dataclasses.replace(p, op=pred_lib.OP_EQ, t1=float(codes[0]),
+                               t2=0.0)
+
+
 def sat_over(p: Predicate) -> Ivl:
     """Superset of the f32 values satisfying ``p`` (thresholds f32-packed)."""
+    if p.op == pred_lib.OP_IN:
+        eq = _in_as_eq(p)
+        if eq is not None:
+            return sat_over(eq)
+        codes = pred_lib.in_codes(p.t1, p.t2)
+        if not codes:
+            return EMPTY
+        return Ivl(codes[0] - 0.5, codes[-1] + 0.5)
     t1, t2 = _f32(p.t1), _f32(p.t2)
     if p.op == pred_lib.OP_GT:
         return Ivl(t1, _INF, lo_open=True)
@@ -151,6 +176,9 @@ def sat_over(p: Predicate) -> Ivl:
 
 def sat_under(p: Predicate) -> Ivl:
     """Subset of the f32 values satisfying ``p`` (∅ when nothing provable)."""
+    if p.op == pred_lib.OP_IN:
+        eq = _in_as_eq(p)
+        return EMPTY if eq is None else sat_under(eq)
     t1, t2 = _f32(p.t1), _f32(p.t2)
     if p.op == pred_lib.OP_GT:
         return Ivl(t1, _INF, lo_open=True)
@@ -181,6 +209,19 @@ def _groups_of(predicates: Sequence[Predicate]) -> list[list[int]]:
     return [members[g] for g in sorted(members)]
 
 
+def _terms_of(predicates: Sequence[Predicate]) -> list[list[list[int]]]:
+    """Per group (``_groups_of`` order), its AND-terms as predicate index
+    lists; a chain without terms has one-atom terms."""
+    tids = pred_lib.normalize_terms(predicates) or range(len(predicates))
+    out = []
+    for members in _groups_of(predicates):
+        terms: dict[int, list[int]] = {}
+        for i in members:
+            terms.setdefault(tids[i], []).append(i)
+        out.append(list(terms.values()))
+    return out
+
+
 def _loc(i: int, p: Predicate) -> str:
     return f"chain[{i}]:{p.name}"
 
@@ -202,6 +243,7 @@ def lint_chain(predicates: Sequence[Predicate],
     preds = list(predicates)
     diags: list[Diagnostic] = []
     groups = _groups_of(preds)
+    group_terms = _terms_of(preds)
     over = [sat_over(p) for p in preds]
     under = [sat_under(p) for p in preds]
 
@@ -215,12 +257,12 @@ def lint_chain(predicates: Sequence[Predicate],
                 f"{p.describe()} is empty over f32",
                 "fix the thresholds (BETWEEN needs t1 < t2 with room for "
                 "an f32 value between) or delete the predicate"))
-    for members in groups:
-        if len(members) > 1 and all(unsat[i] for i in members):
+    for members, terms in zip(groups, group_terms):
+        if len(terms) > 1 and all(any(unsat[i] for i in t) for t in terms):
             label = _group_label(preds, members)
             diags.append(Diagnostic(
                 "chain-unsat-group", "error", f"group {label}",
-                f"every member of OR-group {label} is individually "
+                f"every member (term) of OR-group {label} is "
                 f"unsatisfiable — the group cuts all rows",
                 "fix at least one member or delete the group"))
 
@@ -229,19 +271,24 @@ def lint_chain(predicates: Sequence[Predicate],
     # that column (a mixed-column group can be satisfied elsewhere); the
     # over-approx of an OR is the hull of its members' over-approxes.
     by_col: dict[int, list[tuple[str, Ivl]]] = {}
-    for members in groups:
+    for members, terms in zip(groups, group_terms):
         cols = {preds[i].column for i in members}
         if len(cols) != 1:
             continue
-        if any(unsat[i] for i in members) and not all(
-                unsat[i] for i in members):
-            # hull over live members only (dead ones add nothing to the OR)
-            live = [i for i in members if not unsat[i]]
-        else:
-            live = members
-        gov = over[live[0]]
-        for i in live[1:]:
-            gov = gov.hull(over[i])
+        # a term's over-approx is the intersection of its atoms'
+        tov = []
+        for t in terms:
+            iv = FULL
+            for i in t:
+                iv = iv.intersect(over[i])
+            tov.append(iv)
+        dead = [iv.is_empty() for iv in tov]
+        # hull over live terms only (dead ones add nothing to the OR)
+        live = [iv for iv, d in zip(tov, dead) if not d] \
+            if any(dead) and not all(dead) else tov
+        gov = live[0]
+        for iv in live[1:]:
+            gov = gov.hull(iv)
         label = _group_label(preds, members) if len(members) > 1 \
             else preds[members[0]].name
         by_col.setdefault(cols.pop(), []).append((label, gov))
@@ -287,7 +334,8 @@ def lint_chain(predicates: Sequence[Predicate],
                         "fingerprint changes — see README 'Static "
                         "analysis')"))
                 break
-    for members in groups:
+    for terms in group_terms:
+        members = [t[0] for t in terms if len(t) == 1]
         if len(members) < 2:
             continue
         for j in members:                    # j: the redundant member
@@ -319,8 +367,9 @@ def lint_chain(predicates: Sequence[Predicate],
                 continue
             if under[i].contains(Ivl(_f32(dom[0]), _f32(dom[1]))):
                 always[i] = True
-        for members in groups:
-            hits = [i for i in members if always[i]]
+        for members, terms in zip(groups, group_terms):
+            hits = [i for t in terms if all(always[j] for j in t)
+                    for i in t]
             if not hits:
                 continue
             if len(members) == 1:
@@ -367,9 +416,9 @@ def lint_chain(predicates: Sequence[Predicate],
             seen_keys[(p.column, key)] = (i, r)
 
     # ---- HASHMIX shadowing a group's tile-fail proof ----------------------
-    for members in groups:
-        if len(members) < 2:
-            continue
+    for members, terms in zip(groups, group_terms):
+        if len(members) < 2 or len(terms) < len(members):
+            continue                         # AND-terms: no OR of atoms
         mix = [i for i in members if not _provable(preds[i])]
         provable = [i for i in members if _provable(preds[i])]
         if mix and provable:
@@ -477,27 +526,31 @@ def lint_tile_proofs(predicates: Sequence[Predicate],
     maxs = np.asarray(maxs, np.float32)
     n_tiles = mins.shape[1]
     preds = list(predicates)
-    groups = _groups_of(preds)
     pass_t = np.ones((n_tiles,), bool)
     fail_t = np.zeros((n_tiles,), bool)
-    for members in groups:
+    for terms in _terms_of(preds):
         gp = np.zeros((n_tiles,), bool)
         gf = np.ones((n_tiles,), bool)
-        for i in members:
-            p = preds[i]
-            un, ov = sat_under(p), sat_over(p)
-            mp = np.zeros((n_tiles,), bool)
-            mf = np.zeros((n_tiles,), bool)
-            for t in range(n_tiles):
-                mn = float(mins[p.column, t])
-                mx = float(maxs[p.column, t])
-                if np.isnan(mn) or np.isnan(mx):
-                    continue                 # NaN lanes: never provable
-                hull = Ivl(mn, mx)
-                mp[t] = un.contains(hull)
-                mf[t] = ov.disjoint(hull)
-            gp |= mp
-            gf &= mf
+        for term in terms:                   # AND inside, OR across
+            tp = np.ones((n_tiles,), bool)
+            tf = np.zeros((n_tiles,), bool)
+            for i in term:
+                p = preds[i]
+                un, ov = sat_under(p), sat_over(p)
+                mp = np.zeros((n_tiles,), bool)
+                mf = np.zeros((n_tiles,), bool)
+                for t in range(n_tiles):
+                    mn = float(mins[p.column, t])
+                    mx = float(maxs[p.column, t])
+                    if np.isnan(mn) or np.isnan(mx):
+                        continue             # NaN lanes: never provable
+                    hull = Ivl(mn, mx)
+                    mp[t] = un.contains(hull)
+                    mf[t] = ov.disjoint(hull)
+                tp &= mp
+                tf |= mf
+            gp |= tp
+            gf &= tf
         pass_t &= gp
         fail_t |= gf
     return pass_t & ~fail_t, fail_t

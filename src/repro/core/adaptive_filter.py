@@ -49,7 +49,7 @@ from repro.core import predicates as pred_lib
 from repro.core.engine import MonitorSpec, get_engine
 from repro.core.ordering import OrderingConfig, OrderState
 from repro.core import skip_tier as skip_tier_lib
-from repro.core.plan import validate_combo
+from repro.core.plan import chain_shape, validate_combo
 from repro.core.scope import Scope, reduce_stats, scope_from_str
 from repro.core.predicates import Predicate
 from repro.core.stats import FilterStats
@@ -147,6 +147,9 @@ class StepMetrics(NamedTuple):
     n_tiles_pass: jnp.ndarray       # tiles bulk-kept by the zone-map proof
     n_tiles_fail: jnp.ndarray       # tiles dropped without row-level work
     n_tiles_ambiguous: jnp.ndarray  # tiles that reached the row-level chain
+    # (2,048-row tile, chain position) pairs at which the tiled chain lane
+    # evaluates the atom (``ChainResult.n_atom_tiles``); i32, on the device
+    n_atom_tiles: jnp.ndarray
 
 
 class AdaptiveFilter:
@@ -161,6 +164,17 @@ class AdaptiveFilter:
         self.config = config or AdaptiveFilterConfig()
         self.specs = pred_lib.pack(self.predicates)
         self.groups = self.specs.groups          # static CNF structure
+        self.terms = self.specs.term             # static tree terms; ()
+        shape = chain_shape(self.predicates)
+        if any(shape.values()):          # the config could not see these
+            c = self.config
+            validate_combo(scope=c.scope, cost_mode=c.cost_mode,
+                           backend=c.backend,
+                           compact_output=c.compact_output,
+                           compact_capacity=c.compact_capacity,
+                           compact_slack=c.compact_slack,
+                           exchange=c.exchange, skip_tier=c.skip_tier,
+                           **shape)
         self.axis_names = tuple(axis_names)
         self._scope = scope_from_str(self.config.scope)
         self._engine = get_engine(self.config.backend)
@@ -186,7 +200,8 @@ class AdaptiveFilter:
     # ---------------------------------------------------------------- state
     def init_state(self, xp=jnp) -> OrderState:
         return ordering_lib.init_order_state(
-            len(self.predicates), self.specs.n_groups, xp=xp)
+            len(self.predicates), self.specs.n_groups, xp=xp,
+            n_terms=self.specs.n_terms)
 
     @property
     def jit_step(self):
@@ -268,17 +283,18 @@ class AdaptiveFilter:
                 sample_phase=state.sample_phase, epoch=state.epoch)
         cut, gcut, n_mon = (res.cut_counts, res.group_cut_counts,
                             res.n_monitored)
+        tcut = res.term_cut_counts if self.terms else None
         deferred = self.exchange_deferred
         if (self._scope is Scope.CENTRALIZED and self.axis_names
                 and not deferred):
             merged = reduce_stats(
-                FilterStats(cut, costs, n_mon, gcut), self._scope,
+                FilterStats(cut, costs, n_mon, gcut, tcut), self._scope,
                 self.axis_names)
-            cut, costs, n_mon, gcut = (merged.num_cut, merged.cost_acc,
-                                       merged.n_monitored, merged.group_cut)
+            cut, costs, n_mon, gcut, tcut = merged
         return ordering_lib.advance(
             state, cfg.ordering, cut, costs, n_mon, n_rows=n_rows,
-            group_cut=gcut, groups=self.groups, defer_epoch=deferred)
+            group_cut=gcut, groups=self.groups, defer_epoch=deferred,
+            term_cut=tcut, terms=self.terms)
 
     def _metrics(self, res, perm, new_state, n_dropped=None) -> StepMetrics:
         return StepMetrics(
@@ -294,6 +310,7 @@ class AdaptiveFilter:
             n_tiles_pass=jnp.asarray(res.n_tiles_pass, jnp.int32),
             n_tiles_fail=jnp.asarray(res.n_tiles_fail, jnp.int32),
             n_tiles_ambiguous=jnp.asarray(res.n_tiles_ambiguous, jnp.int32),
+            n_atom_tiles=jnp.asarray(res.n_atom_tiles, jnp.int32),
         )
 
     def _perm(self, state: OrderState):
@@ -474,7 +491,7 @@ class AdaptiveFilter:
             due,
             lambda s: ordering_lib.boundary_update(
                 s, self.config.ordering, groups=self.groups,
-                stats_override=use),
+                stats_override=use, terms=self.terms),
             lambda s: s, state)
         return new_state, stash
 
@@ -552,7 +569,9 @@ class AdaptiveFilter:
                     state, cfg.ordering, res.cut_counts, res.monitor_cost,
                     res.n_monitored, n_rows=batch.shape[1],
                     group_cut=res.group_cut_counts, groups=self.groups,
-                    xp=np, defer_epoch=defer)
+                    xp=np, defer_epoch=defer,
+                    term_cut=res.term_cut_counts if self.terms else None,
+                    terms=self.terms)
                 if defer and state.rows_into_epoch >= \
                         cfg.ordering.calculate_rate:
                     # no mesh on the host path: the "exchange" is the
@@ -562,7 +581,7 @@ class AdaptiveFilter:
                     merged = state.stats
                     state = ordering_lib.boundary_update(
                         state, cfg.ordering, groups=self.groups, xp=np,
-                        stats_override=stash)
+                        stats_override=stash, terms=self.terms)
                     if cfg.exchange == "deferred-async":
                         stash = merged
             else:

@@ -3,7 +3,8 @@
 An engine turns (columns, packed predicate specs, permutation, monitor
 config) into a ``ChainResult``. The semantics are fixed — CNF evaluation
 (OR within a group, AND across groups, short-circuit at both levels, exact
-row-level work accounting) plus the paper's §2.1 monitor lane — and are
+row-level work accounting; trees add AND-terms inside groups, with the AND
+short-circuit inside each term) plus the paper's §2.1 monitor lane — and are
 pinned across engines by the conformance tests; only the execution strategy
 (masked jnp, fused Pallas tiles, compacted numpy) differs.
 
@@ -16,6 +17,10 @@ correct ``ChainResult``.
 from __future__ import annotations
 
 from typing import Any, NamedTuple, Protocol, runtime_checkable
+
+#: rows per grid tile of the tiled chain lane (the pallas kernel's default
+#: tile), and the tile every engine counts ``n_atom_tiles`` over
+CHAIN_TILE = 2048
 
 
 class ChainResult(NamedTuple):
@@ -34,6 +39,14 @@ class ChainResult(NamedTuple):
     n_tiles_pass: Any = 0       # i32[] — tiles provably passing every group
     n_tiles_fail: Any = 0       # i32[] — tiles provably failing some group
     n_tiles_ambiguous: Any = 0  # i32[] — tiles sent to the row-level chain
+    # monitor lane: rows cut by each AND-term of a tree (empty f32[0] for
+    # chains without terms; None where an engine does not report it)
+    term_cut_counts: Any = None  # f32[T] | None
+    # (CHAIN_TILE-row tile, chain position) pairs with a row pending: the
+    # pairs at which a tiled chain lane evaluates the atom (the pallas
+    # kernel's tile short-circuit skips the others); 0 where the skip
+    # tier's gather changes the tiling (the jnp skip path)
+    n_atom_tiles: Any = 0       # i32[]
 
 
 class SkipInfo(NamedTuple):

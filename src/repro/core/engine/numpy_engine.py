@@ -39,28 +39,28 @@ class NumpyEngine:
     supports_skip = True
     skip_gathers = False     # host path indexes ambiguous rows directly
 
-    def _monitor(self, columns, preds, monitor: MonitorSpec, groups):
-        cut, group_cut, n_mon, secs = np_exec.run_monitor_np(
+    def _monitor(self, columns, preds, monitor: MonitorSpec, specs):
+        cut, group_cut, n_mon, secs, term_cut = np_exec.run_monitor_np(
             columns, preds, monitor.collect_rate,
-            int(monitor.sample_phase), groups=groups)
+            int(monitor.sample_phase), groups=specs.groups, terms=specs.term)
         if monitor.cost_mode == "measured":
             monitor_cost = secs
         else:
             monitor_cost = np.asarray(
                 [p.static_cost for p in preds], np.float64) * n_mon
-        return cut, group_cut, n_mon, monitor_cost
+        return cut, group_cut, n_mon, monitor_cost, \
+            term_cut.astype(np.float32)
 
     def run_chain(self, columns, specs, perm,
                   monitor: MonitorSpec) -> ChainResult:
         columns = np.asarray(columns)
         preds = _preds_from_specs(specs)
-        groups = specs.groups
         perm = np.asarray(perm)
 
-        mask, work, active_before = np_exec.run_chain_np(
-            columns, preds, perm, groups=groups)
-        cut, group_cut, n_mon, monitor_cost = self._monitor(
-            columns, preds, monitor, groups)
+        mask, work, active_before, atom_tiles = np_exec.run_chain_np(
+            columns, preds, perm, groups=specs.groups, terms=specs.term)
+        cut, group_cut, n_mon, monitor_cost, term_cut = self._monitor(
+            columns, preds, monitor, specs)
         return ChainResult(
             mask=mask,
             work_units=np.float32(work),
@@ -69,6 +69,8 @@ class NumpyEngine:
             n_monitored=np.float32(n_mon),
             monitor_cost=monitor_cost.astype(np.float32),
             group_cut_counts=group_cut.astype(np.float32),
+            term_cut_counts=term_cut,
+            n_atom_tiles=np.int32(atom_tiles),
         )
 
     # ------------------------------------------------------- skip tier
@@ -104,16 +106,16 @@ class NumpyEngine:
                 np.arange(tile)[None, :]).reshape(-1)
         rows = rows[rows < n_rows]
 
-        sub_mask, work, active_before = np_exec.run_chain_np(
-            columns[:, rows], preds, perm, groups=groups)
+        sub_mask, work, active_before, _ = np_exec.run_chain_np(
+            columns[:, rows], preds, perm, groups=groups, count_tiles=False)
         mask = np.zeros(n_rows, bool)
         prows = (np.nonzero(pass_t)[0][:, None] * tile +
                  np.arange(tile)[None, :]).reshape(-1)
         mask[prows[prows < n_rows]] = True
         mask[rows] = sub_mask
 
-        cut, group_cut, n_mon, monitor_cost = self._monitor(
-            columns, preds, monitor, groups)
+        cut, group_cut, n_mon, monitor_cost, _ = self._monitor(
+            columns, preds, monitor, specs)
         n_pass_t, n_fail_t, n_amb_t = skip_tier.tile_counters(skip, np)
         return ChainResult(
             mask=mask,

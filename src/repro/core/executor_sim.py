@@ -118,9 +118,10 @@ def run_executor(predicates: Sequence[Predicate],
             except queue.Empty:
                 return
             perm = state.perm if adaptive else np.arange(n_preds)
-            mask, work, _ = np_exec.run_chain_np(part, predicates, perm)
+            mask, work, _, _ = np_exec.run_chain_np(part, predicates, perm,
+                                                    count_tiles=False)
             if adaptive:
-                cut, _gcut, m, secs = np_exec.run_monitor_np(
+                cut, _gcut, m, secs, _ = np_exec.run_monitor_np(
                     part, predicates, cfg.collect_rate, sample_phase)
                 num_cut += cut
                 if cost_mode == "measured":

@@ -21,6 +21,8 @@ evaluating an OR-group's members once one passes, and stops entirely once a
 group rejects it. ``perm`` must keep each group's members contiguous
 (``stats.cnf_order`` guarantees it); flat chains (all singleton groups) are
 the degenerate case and reproduce the paper's conjunction bit-exactly.
+Trees add AND-terms inside groups: a row evaluates a term's atoms until one
+fails, the group's terms until one passes, and the groups until one fails.
 
 Monitor lane (paper §2.1): rows with (global_row_index % collect_rate == 0)
 are sampled; *all* predicates are evaluated on them (correlation-bias-free),
@@ -35,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import predicates as pred_lib
-from repro.core.engine.base import ChainResult
+from repro.core.engine.base import CHAIN_TILE, ChainResult
 from repro.core.predicates import PredicateSpecs
 
 __all__ = ["ChainResult", "monitor_indices", "run_monitor", "run_chain",
@@ -62,7 +64,7 @@ def run_monitor(columns: jnp.ndarray, specs: PredicateSpecs,
     """Evaluate ALL predicates on the sampled rows only.
 
     Returns (cut f32[P], group_cut f32[G], n_monitored f32[],
-    monitor_cost f32[P]).
+    monitor_cost f32[P], term_cut f32[T] — empty without terms).
     """
     n_rows = columns.shape[1]
     idx, valid = monitor_indices(n_rows, collect_rate, sample_phase)
@@ -71,59 +73,98 @@ def run_monitor(columns: jnp.ndarray, specs: PredicateSpecs,
     cut = jnp.sum(jnp.logical_and(~results, valid[None, :]), axis=1)
     # group cut: a sampled row is cut by group g iff NO member passes —
     # exact (the monitor lane sees the full outcome matrix, so group
-    # selectivities carry no independence assumption).
+    # selectivities carry no independence assumption). In a tree the
+    # members are terms: a term fails when any of its atoms fails.
+    if specs.is_tree:
+        term_fail = jnp.stack(
+            [jnp.any(~results[jnp.asarray(m)], axis=0)
+             for m in specs.term_members])         # bool[T, max_samples]
+        term_cut = jnp.sum(jnp.logical_and(term_fail, valid[None, :]),
+                           axis=1).astype(jnp.float32)
+        group_terms = specs.group_terms
+    else:                                          # one term per atom
+        term_fail, term_cut = ~results, jnp.zeros((0,), jnp.float32)
+        group_terms = specs.group_members
     group_fail = jnp.stack(
-        [jnp.all(~results[jnp.asarray(m)], axis=0)
-         for m in specs.group_members])            # bool[G, max_samples]
+        [jnp.all(term_fail[jnp.asarray(t)], axis=0)
+         for t in group_terms])                    # bool[G, max_samples]
     group_cut = jnp.sum(jnp.logical_and(group_fail, valid[None, :]), axis=1)
     n_monitored = jnp.sum(valid).astype(jnp.float32)
     # STATIC cost model: each sampled row pays every predicate's calibrated
     # per-row cost (the monitor lane evaluates all of them, as in the paper).
     monitor_cost = specs.static_cost * n_monitored
     return (cut.astype(jnp.float32), group_cut.astype(jnp.float32),
-            n_monitored, monitor_cost)
+            n_monitored, monitor_cost, term_cut)
+
+
+def tiles_pending(pending) -> jnp.ndarray:
+    """i32[]: CHAIN_TILE-row tiles of ``pending`` (bool[R]) with a row set."""
+    pad = (-pending.shape[0]) % CHAIN_TILE
+    p = jnp.pad(pending, (0, pad)) if pad else pending
+    return jnp.sum(jnp.any(p.reshape(-1, CHAIN_TILE), axis=1),
+                   dtype=jnp.int32)
 
 
 def run_chain_masks(columns: jnp.ndarray, specs: PredicateSpecs,
-                    perm: jnp.ndarray, valid=None):
-    """Chain lane only (no monitor): masked CNF evaluation in ``perm`` order.
+                    perm: jnp.ndarray, valid=None, count_tiles: bool = True):
+    """Chain lane only (no monitor): masked evaluation in ``perm`` order.
 
-    Returns (mask bool[R], work f32[], active_before f32[P]). ``valid``
+    Returns (mask bool[R], work f32[], active_before f32[P], n_atom_tiles
+    i32[] — see ``ChainResult`` — or None without ``count_tiles``, static:
+    the skip tier's gathered buffer has no tiles of its own). ``valid``
     (bool[R], optional) pre-cuts rows before the first predicate: the skip
     tier's gathered ambiguous buffer uses it so padding and unused gather
     slots are neither kept nor charged to the work counters.
     """
     n_rows = columns.shape[1]
-    n_preds = specs.n
+    last = specs.n - 1
     flat = specs.is_flat                  # static → branch folds at trace
+    tree = specs.is_tree                  # static: a CNF chain has no terms
     garr = jnp.asarray(specs.groups, jnp.int32)
+    tarr = jnp.asarray(specs.term, jnp.int32) if tree else None
 
     # survivors of all CLOSED groups
     mask = jnp.ones((n_rows,), bool) if valid is None else valid
     group_or = jnp.zeros((n_rows,), bool)  # passes within the OPEN group
+    term_and = None                        # rows the OPEN term keeps
     work = jnp.zeros((), jnp.float32)
     active_before = []
+    atom_tiles = jnp.zeros((), jnp.int32) if count_tiles else None
 
-    for k in range(n_preds):          # P is small & static → unrolled, lazy ops
+    for k in range(specs.n):          # P is small & static → unrolled, lazy ops
         i = perm[k]
-        # is_first/closes are group-boundary flags; static True when flat,
-        # traced scalars otherwise (perm is dynamic under jit).
+        # group and term boundary flags: static True when flat (groups) or
+        # without terms (every atom its own term), traced scalars otherwise
+        # (perm is dynamic under jit).
         is_first = True if (flat or k == 0) else (garr[perm[k - 1]] != garr[i])
-        closes = True if (flat or k == n_preds - 1) \
+        closes = True if (flat or k == last) \
             else (garr[perm[k + 1]] != garr[i])
+        opens_term = True if (not tree or k == 0) \
+            else (tarr[perm[k - 1]] != tarr[i])
+        closes_term = True if (not tree or k == last) \
+            else (tarr[perm[k + 1]] != tarr[i])
         pending = mask if is_first is True \
             else jnp.where(is_first, mask, jnp.logical_and(mask, ~group_or))
+        if opens_term is not True:
+            pending = jnp.where(opens_term, pending,
+                                jnp.logical_and(pending, term_and))
         alive = jnp.sum(pending).astype(jnp.float32)
         active_before.append(alive)
+        if count_tiles:
+            atom_tiles = atom_tiles + tiles_pending(pending)
         work = work + alive * specs.static_cost[i]
         x = jnp.take(columns, specs.column[i], axis=0)
         res = pred_lib.eval_one(specs, i, x)
-        group_or = res if is_first is True \
-            else jnp.where(is_first, res, jnp.logical_or(group_or, res))
+        term_and = res if opens_term is True \
+            else jnp.where(opens_term, res, jnp.logical_and(term_and, res))
+        term_pass = term_and if closes_term is True \
+            else jnp.logical_and(term_and, closes_term)
+        group_or = term_pass if is_first is True \
+            else jnp.where(is_first, False, group_or) | term_pass
         new_mask = jnp.logical_and(mask, group_or)
         mask = new_mask if closes is True else jnp.where(closes, new_mask, mask)
 
-    return mask, work, jnp.stack(active_before)
+    return mask, work, jnp.stack(active_before), atom_tiles
 
 
 def run_chain(columns: jnp.ndarray, specs: PredicateSpecs, perm: jnp.ndarray,
@@ -137,9 +178,10 @@ def run_chain(columns: jnp.ndarray, specs: PredicateSpecs, perm: jnp.ndarray,
     earlier member of the current group (what a row-at-a-time engine with
     both short-circuits would evaluate).
     """
-    mask, work, active_before = run_chain_masks(columns, specs, perm)
+    mask, work, active_before, atom_tiles = run_chain_masks(columns, specs,
+                                                            perm)
 
-    cut, group_cut, n_mon, mon_cost = run_monitor(
+    cut, group_cut, n_mon, mon_cost, term_cut = run_monitor(
         columns, specs, collect_rate, sample_phase)
 
     return ChainResult(
@@ -150,6 +192,8 @@ def run_chain(columns: jnp.ndarray, specs: PredicateSpecs, perm: jnp.ndarray,
         n_monitored=n_mon,
         monitor_cost=mon_cost,
         group_cut_counts=group_cut,
+        term_cut_counts=term_cut,
+        n_atom_tiles=atom_tiles,
     )
 
 

@@ -25,6 +25,11 @@ exact P(group passes) from the monitor lane, cost = Σ member costs) and
 momentum-smoothed at group granularity; members are ordered within their
 group by miss-rate each epoch. For flat chains (all singleton groups) this
 reduces bit-exactly to the paper's per-predicate ordering.
+
+Trees (AND-terms inside OR-groups, ``terms`` given): groups keep their
+momentum-smoothed ranks, with a group's cost its expected cost under the
+term order; terms are ordered inside each group and atoms inside each term
+every epoch, from the same epoch's evidence (``stats.tree_levels``).
 """
 
 from __future__ import annotations
@@ -35,6 +40,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import predicates as pred_lib
 from repro.core import stats as stats_lib
 from repro.core.stats import FilterStats
 
@@ -81,14 +87,15 @@ class OrderState(NamedTuple):
 
 
 def init_order_state(n_predicates: int, n_groups: int | None = None,
-                     xp=jnp) -> OrderState:
+                     xp=jnp, n_terms: int = 0) -> OrderState:
     """Initial order = the user-given statement order, as in Spark."""
     if n_groups is None:
         n_groups = n_predicates
     return OrderState(
         perm=xp.arange(n_predicates, dtype=xp.int32),
         adj_rank=xp.zeros((n_groups,), xp.float32),
-        stats=stats_lib.init_stats(n_predicates, n_groups, xp=xp),
+        stats=stats_lib.init_stats(n_predicates, n_groups, xp=xp,
+                                   n_terms=n_terms),
         rows_into_epoch=xp.zeros((), xp.int32),
         sample_phase=xp.zeros((), xp.int32),
         epoch=xp.zeros((), xp.int32),
@@ -101,11 +108,14 @@ def _default_groups(state: OrderState) -> tuple:
 
 
 def epoch_update(state: OrderState, cfg: OrderingConfig,
-                 groups: tuple | None = None, xp=jnp) -> OrderState:
+                 groups: tuple | None = None, xp=jnp,
+                 terms: tuple | None = None) -> OrderState:
     """Re-rank at an epoch boundary; reset accumulators; keep momentum memory.
 
     ``groups`` is the static CNF structure (dense group id per predicate);
-    None means all-singleton groups (flat conjunction).
+    None means all-singleton groups (flat conjunction). ``terms`` is the
+    static term structure of a tree (dense term id per predicate); None
+    or () for chains without terms.
 
     Guard: if the epoch collected no monitored rows (possible with tiny
     epochs), keep the previous order — reordering on zero evidence is the
@@ -116,11 +126,17 @@ def epoch_update(state: OrderState, cfg: OrderingConfig,
     n_groups = int(state.adj_rank.shape[0])
     have_evidence = state.stats.n_monitored > 0.0
 
-    rank_now = stats_lib.group_ranks(state.stats, groups, xp=xp)
+    if terms:
+        term_members, group_terms = pred_lib.tree_structure(groups, terms)
+        rank_now, term_rank, atom_rank, gnc = stats_lib.tree_levels(
+            state.stats, term_members, group_terms, xp=xp)
+    else:
+        rank_now = stats_lib.group_ranks(state.stats, groups, xp=xp)
     adj = stats_lib.momentum_update(state.adj_rank, rank_now, cfg.momentum,
                                     first_epoch=state.epoch == 0, xp=xp)
     if cfg.snap_threshold > 0.0:
-        nc = stats_lib.group_normalized_costs(state.stats, groups, xp=xp)
+        nc = gnc if terms else stats_lib.group_normalized_costs(
+            state.stats, groups, xp=xp)
         s = stats_lib.group_selectivities(state.stats, xp=xp)
         cost_cur = stats_lib.expected_chain_cost(nc, s, state.group_perm,
                                                  xp=xp)
@@ -128,8 +144,13 @@ def epoch_update(state: OrderState, cfg: OrderingConfig,
         cost_fresh = stats_lib.expected_chain_cost(nc, s, fresh, xp=xp)
         snap = cost_cur > cfg.snap_threshold * cost_fresh
         adj = xp.where(snap, rank_now, adj)
-    mrank = stats_lib.member_ranks(state.stats, xp=xp)
-    new_perm, new_group_perm = stats_lib.cnf_order(adj, mrank, groups, xp=xp)
+    if terms:
+        new_perm, new_group_perm = stats_lib.tree_order(
+            adj, term_rank, atom_rank, groups, terms, xp=xp)
+    else:
+        mrank = stats_lib.member_ranks(state.stats, xp=xp)
+        new_perm, new_group_perm = stats_lib.cnf_order(adj, mrank, groups,
+                                                       xp=xp)
 
     perm = xp.where(have_evidence, new_perm, state.perm)
     group_perm = xp.where(have_evidence, new_group_perm, state.group_perm)
@@ -139,7 +160,8 @@ def epoch_update(state: OrderState, cfg: OrderingConfig,
     return OrderState(
         perm=perm,
         adj_rank=adj_rank,
-        stats=stats_lib.init_stats(n_preds, n_groups, xp=xp),
+        stats=stats_lib.init_stats(n_preds, n_groups, xp=xp,
+                                   n_terms=len(set(terms or ()))),
         rows_into_epoch=xp.zeros((), xp.int32),
         sample_phase=state.sample_phase,
         epoch=epoch,
@@ -150,7 +172,8 @@ def epoch_update(state: OrderState, cfg: OrderingConfig,
 def advance(state: OrderState, cfg: OrderingConfig,
             cut_counts, costs, n_monitored, n_rows: int,
             group_cut=None, groups: tuple | None = None,
-            xp=jnp, defer_epoch: bool = False) -> OrderState:
+            xp=jnp, defer_epoch: bool = False, term_cut=None,
+            terms: tuple | None = None) -> OrderState:
     """Fold one batch's monitor results in; fire the epoch boundary if crossed.
 
     Epoch boundaries are honored at batch granularity (a batch is the unit of
@@ -166,7 +189,8 @@ def advance(state: OrderState, cfg: OrderingConfig,
     compiled module then contains no all-reduce at all).
     """
     new_stats = stats_lib.accumulate(state.stats, cut_counts, costs,
-                                     n_monitored, group_cut=group_cut, xp=xp)
+                                     n_monitored, group_cut=group_cut, xp=xp,
+                                     term_cut=term_cut)
     rows = state.rows_into_epoch + xp.asarray(n_rows, xp.int32)
     state = state._replace(
         stats=new_stats,
@@ -177,7 +201,7 @@ def advance(state: OrderState, cfg: OrderingConfig,
         return state
 
     def fire(s: OrderState) -> OrderState:
-        updated = epoch_update(s, cfg, groups=groups, xp=xp)
+        updated = epoch_update(s, cfg, groups=groups, xp=xp, terms=terms)
         # carry the overshoot so epoch length is exact on average
         return updated._replace(rows_into_epoch=s.rows_into_epoch % cfg.calculate_rate)
 
@@ -189,7 +213,8 @@ def advance(state: OrderState, cfg: OrderingConfig,
 
 def boundary_update(state: OrderState, cfg: OrderingConfig,
                     groups: tuple | None = None, xp=jnp,
-                    stats_override: FilterStats | None = None) -> OrderState:
+                    stats_override: FilterStats | None = None,
+                    terms: tuple | None = None) -> OrderState:
     """Explicit epoch-boundary update for the deferred-exchange path.
 
     Equivalent to the ``fire`` branch of ``advance`` — re-rank, reset
@@ -200,6 +225,6 @@ def boundary_update(state: OrderState, cfg: OrderingConfig,
     """
     if stats_override is not None:
         state = state._replace(stats=stats_override)
-    updated = epoch_update(state, cfg, groups=groups, xp=xp)
+    updated = epoch_update(state, cfg, groups=groups, xp=xp, terms=terms)
     return updated._replace(
         rows_into_epoch=state.rows_into_epoch % cfg.calculate_rate)

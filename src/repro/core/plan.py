@@ -44,6 +44,11 @@ Valid field combinations (the single source of truth):
               drives it by measured us_per_row. Survivors, tokens, and
               ordering statistics are bit-identical with the tier on or
               off; only speed changes.
+  predicates  a chain with AND-terms (``Predicate.term``) or OP_IN atoms
+              needs skip_tier="off" (the zone-map and Bloom proofs cover
+              flat and CNF chains of the other ops only); one with
+              AND-terms also needs shards == 1 (the elastic reshard
+              re-derives CNF orders only).
 
 Two plans are checkpoint-compatible iff their *fingerprints* match: the
 fingerprint hashes the semantic identity of the adaptive state (predicate
@@ -65,7 +70,7 @@ from typing import Sequence
 from repro.core import engine as engine_lib
 from repro.core.engine import get_engine
 from repro.core.ordering import OrderingConfig
-from repro.core.predicates import Predicate
+from repro.core.predicates import OP_IN, Predicate
 from repro.core.scope import EXCHANGE_MODES, scope_from_str
 from repro.core.skip_tier import SKIP_TIER_MODES
 
@@ -107,11 +112,18 @@ def warn_deprecated(key: str, message: str) -> None:
 
 
 # ------------------------------------------------------ cross-field rules
+def chain_shape(predicates: Sequence[Predicate]) -> dict:
+    """The chain facts ``validate_combo`` checks: AND-terms, OP_IN atoms."""
+    return {"terms": any(p.term is not None for p in predicates),
+            "in_atoms": any(p.op == OP_IN for p in predicates)}
+
+
 def validate_combo(*, scope: str, cost_mode: str, backend: str,
                    compact_output: bool, compact_capacity,
                    compact_slack: float, exchange: str, shards: int = 1,
                    device_tokenize: bool = False,
-                   skip_tier: str = "off") -> None:
+                   skip_tier: str = "off", terms: bool = False,
+                   in_atoms: bool = False) -> None:
     """THE cross-field validation for every engine × scope × compaction ×
     exchange × tokenize combination.
 
@@ -202,6 +214,17 @@ def validate_combo(*, scope: str, cost_mode: str, backend: str,
                 "skip_tier='auto' is driven by the session's online "
                 "us_per_row tuner, which needs a traceable engine — pick "
                 "'zonemap'/'zonemap+bloom' explicitly for host engines")
+        if terms or in_atoms:
+            problems.append(
+                f"skip_tier {skip_tier!r} cannot prove tiles of a chain "
+                "with AND-terms or OP_IN atoms (its zone-map and Bloom "
+                "proofs cover the other ops in flat and CNF chains) — use "
+                "skip_tier='off'")
+    if terms and shards > 1:
+        problems.append(
+            f"shards={shards}: a chain with AND-terms runs on one shard "
+            "(the elastic reshard re-derives CNF orders only) — use "
+            "shards=1")
     if problems:
         if len(problems) == 1:
             raise ValueError(problems[0])
@@ -261,7 +284,8 @@ class FilterPlan:
                        compact_slack=self.slack, exchange=self.exchange,
                        shards=self.shards,
                        device_tokenize=self.tokenize is not None,
-                       skip_tier=self.skip_tier)
+                       skip_tier=self.skip_tier,
+                       **chain_shape(self.predicates))
 
     # ------------------------------------------------------------ identity
     def fingerprint(self) -> str:
@@ -273,10 +297,14 @@ class FilterPlan:
         shard count explicitly so, that is what elastic reshard is; the
         skip tier never changes survivors or statistics, only speed).
         """
+        # a term label is appended only where one is set, so chains
+        # without terms keep the fingerprints they always had (an IN set
+        # is in t1/t2)
         payload = {
             "predicates": [
                 (p.name, p.column, p.op, p.t1, p.t2, p.rounds,
                  p.static_cost, None if p.group is None else str(p.group))
+                + (() if p.term is None else (str(p.term),))
                 for p in self.predicates],
             "ordering": dataclasses.asdict(self.ordering),
             "scope": self.scope,

@@ -82,6 +82,8 @@ def reduce_stats(stats: FilterStats, scope: Scope,
             n_monitored=jax.lax.psum(stats.n_monitored, axis_names),
             group_cut=None if stats.group_cut is None
             else jax.lax.psum(stats.group_cut, axis_names),
+            term_cut=None if stats.term_cut is None
+            else jax.lax.psum(stats.term_cut, axis_names),
         )
     return stats
 

@@ -218,8 +218,9 @@ def state_invariants(state: OrderState, *, n_predicates: int, n_groups: int,
 
       * all stat accumulators and ``adj_rank`` finite; accumulators and
         counters non-negative (a NaN/Inf here poisons every future rank);
-      * ``num_cut``/``group_cut`` never exceed ``n_monitored`` (a count
-        above the monitored total cannot arise from any real batch);
+      * ``num_cut``/``group_cut``/``term_cut`` never exceed
+        ``n_monitored`` (a count above the monitored total cannot arise
+        from any real batch);
       * ``perm`` is a permutation of [0, P) and ``group_perm`` of [0, G)
         (a clamped out-of-bounds gather silently evaluates the wrong
         predicate — the worst kind of corruption: no crash, wrong masks);
@@ -244,6 +245,8 @@ def state_invariants(state: OrderState, *, n_predicates: int, n_groups: int,
     ok &= xp.all(st.num_cut <= n_mon)
     if st.group_cut is not None:
         ok &= clean(st.group_cut) & xp.all(st.group_cut <= n_mon)
+    if st.term_cut is not None:
+        ok &= clean(st.term_cut) & xp.all(st.term_cut <= n_mon)
     ok &= xp.all(xp.sort(state.perm.astype(xp.int32), axis=-1)
                  == xp.arange(n_predicates, dtype=xp.int32))
     if state.group_perm is not None:
@@ -906,7 +909,7 @@ def _layout_of(arrays: dict) -> int:
     return 0 if rows.ndim == 0 else int(rows.shape[0])
 
 _SUM_KEYS = ("stats.num_cut", "stats.cost_acc", "stats.n_monitored",
-             "stats.group_cut", "rows_into_epoch")
+             "stats.group_cut", "stats.term_cut", "rows_into_epoch")
 
 
 def reshard_state_arrays(arrays: dict, new_shards: int, groups: tuple,

@@ -260,8 +260,8 @@ def run_chain_skip_jnp(columns, specs, perm, monitor, skip: SkipInfo,
     gid = tile_idx[:, None] * SKIP_TILE + jnp.arange(SKIP_TILE)[None, :]
     valid = (gid < n_rows).reshape(-1)           # unused slots + ragged tail
 
-    amb_mask, work, active = filter_exec.run_chain_masks(
-        gcols, specs, perm, valid=valid)
+    amb_mask, work, active, _ = filter_exec.run_chain_masks(
+        gcols, specs, perm, valid=valid, count_tiles=False)
 
     mask_tiles = jnp.broadcast_to(skip.pass_tiles[:, None],
                                   (n_tiles, SKIP_TILE))
@@ -269,7 +269,7 @@ def run_chain_skip_jnp(columns, specs, perm, monitor, skip: SkipInfo,
         amb_mask.reshape(amb_cap, SKIP_TILE), mode="drop")
     mask = mask_tiles.reshape(-1)[:n_rows]
 
-    cut, gcut, n_mon, mon_cost = filter_exec.run_monitor(
+    cut, gcut, n_mon, mon_cost, _ = filter_exec.run_monitor(
         columns, specs, monitor.collect_rate, monitor.sample_phase)
 
     n_pass_t, n_fail_t, n_amb_t = tile_counters(skip, jnp)

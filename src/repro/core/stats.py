@@ -24,6 +24,15 @@ CNF extension (AND of OR-groups): the same machinery lifts to *groups* —
     on the first PASS, so cheap high-pass-rate members go first
     (member rank = nc_i / s_i — the conjunction formula with s ↔ 1-s).
 
+Tree extension (AND of ORs of AND-terms; ``tree_order``): the monitor lane
+also counts ``term_cut[t]``, the sampled rows term t cuts (some atom
+fails), so P(term passes) is exact too. Atoms inside a term are ordered by
+their conjunction rank, terms inside a group by expected term cost over
+P(term passes), and groups by rank as above — with a group's cost its
+expected cost under that term order (Σ member costs would overstate an OR
+that stops at its first passing term). Chains without terms never reach
+this code, so their ordering is unchanged bit for bit.
+
 Every function here is **backend-agnostic**: it takes an array-namespace
 argument ``xp`` (``jax.numpy`` or ``numpy``) and runs the identical code
 path on either, so there is exactly one implementation of the rank math for
@@ -71,16 +80,18 @@ class FilterStats(NamedTuple):
 
     ``group_cut`` is None for consumers that predate CNF (flat chains treat
     every predicate as its own group, where group_cut ≡ num_cut).
+    ``term_cut`` is None unless the chain has AND-terms.
     """
 
     num_cut: Any       # f32[P]
     cost_acc: Any      # f32[P]
     n_monitored: Any   # f32[]
     group_cut: Any = None  # f32[G] | None
+    term_cut: Any = None   # f32[T] | None
 
 
 def init_stats(n_predicates: int, n_groups: int | None = None,
-               xp=jnp) -> FilterStats:
+               xp=jnp, n_terms: int = 0) -> FilterStats:
     if n_groups is None:
         n_groups = n_predicates
     return FilterStats(
@@ -88,20 +99,23 @@ def init_stats(n_predicates: int, n_groups: int | None = None,
         cost_acc=xp.zeros((n_predicates,), xp.float32),
         n_monitored=xp.zeros((), xp.float32),
         group_cut=xp.zeros((n_groups,), xp.float32),
+        term_cut=xp.zeros((n_terms,), xp.float32) if n_terms else None,
     )
 
 
 def merge_stats(a: FilterStats, b: FilterStats) -> FilterStats:
     """Associative merge (used by the centralized scope's psum and by tests)."""
-    gc = None
+    gc = tc = None
     if a.group_cut is not None and b.group_cut is not None:
         gc = a.group_cut + b.group_cut
+    if a.term_cut is not None and b.term_cut is not None:
+        tc = a.term_cut + b.term_cut
     return FilterStats(a.num_cut + b.num_cut, a.cost_acc + b.cost_acc,
-                       a.n_monitored + b.n_monitored, gc)
+                       a.n_monitored + b.n_monitored, gc, tc)
 
 
 def accumulate(stats: FilterStats, cut_counts, costs, n_monitored,
-               group_cut=None, xp=jnp) -> FilterStats:
+               group_cut=None, xp=jnp, term_cut=None) -> FilterStats:
     """Fold one batch's monitor-lane results into the epoch accumulators.
 
     Saturation guard (see ``SAT_THRESHOLD``): once the epoch has monitored
@@ -119,12 +133,16 @@ def accumulate(stats: FilterStats, cut_counts, costs, n_monitored,
     else:
         inc = cut_counts if group_cut is None else group_cut
         new_gc = stats.group_cut * scale + inc.astype(xp.float32)
+    new_tc = None
+    if stats.term_cut is not None:
+        new_tc = stats.term_cut * scale + term_cut.astype(xp.float32)
     return FilterStats(
         num_cut=stats.num_cut * scale + cut_counts.astype(xp.float32),
         cost_acc=stats.cost_acc * scale + costs.astype(xp.float32),
         n_monitored=stats.n_monitored * scale
         + xp.asarray(n_monitored, xp.float32),
         group_cut=new_gc,
+        term_cut=new_tc,
     )
 
 
@@ -239,8 +257,81 @@ def cnf_order(group_adj_rank, member_rank, groups, xp=jnp):
     return perm.astype(xp.int32), group_perm
 
 
+# ------------------------------------------------------------------ trees
+def term_selectivities(stats: FilterStats, xp=jnp):
+    """Exact P(term passes) from the monitor lane's term-cut counters,
+    computed as ``selectivities`` is, so a one-atom term's equals its
+    atom's bit for bit."""
+    n = xp.maximum(stats.n_monitored, 1.0)
+    return xp.clip(1.0 - stats.term_cut / n, 0.0, 1.0)
+
+
+def tree_levels(stats: FilterStats, term_members, group_terms, xp=jnp):
+    """The three levels of a tree's ordering (``predicates.
+    tree_cost_row_model``).
+
+    Returns (group rank f32[G], term rank f32[T], atom rank f32[P],
+    group cost f32[G]): atoms rank by the conjunction rank nc/(1-s) (an
+    AND stops at the first failing atom); terms by C_t / P_t, C_t the
+    term's expected cost in that atom order (an OR stops at the first
+    passing term — for a one-atom term this is ``member_ranks`` bit for
+    bit); groups as ``group_ranks`` does, C_g / (1 - S_g), with C_g the
+    group's expected cost in that term order normalized by the largest
+    group's. ``term_members``/``group_terms`` are the static structure of
+    ``PredicateSpecs``.
+    """
+    s = selectivities(stats, xp=xp)
+    nc = normalized_costs(stats, xp=xp)
+    atom_rank = nc / xp.maximum(1.0 - s, _EPS)
+    p_term = term_selectivities(stats, xp=xp)
+    t_cost = []
+    for mem in term_members:                     # static unroll
+        idx = xp.asarray(list(mem), xp.int32)
+        order = idx[argsort_stable(atom_rank[idx], xp=xp)]
+        t_cost.append(expected_chain_cost(nc, s, order, xp=xp))
+    t_cost = xp.stack(t_cost)
+    term_rank = t_cost / xp.maximum(p_term, _EPS)
+    g_cost = []
+    for terms in group_terms:
+        idx = xp.asarray(list(terms), xp.int32)
+        order = idx[argsort_stable(term_rank[idx], xp=xp)]
+        g_cost.append(expected_chain_cost(t_cost, 1.0 - p_term, order,
+                                          xp=xp))
+    g_cost = xp.stack(g_cost)
+    gnc = g_cost / xp.maximum(xp.max(g_cost), _EPS)
+    group_rank = gnc / xp.maximum(1.0 - group_selectivities(stats, xp=xp),
+                                  _EPS)
+    return group_rank, term_rank, atom_rank, gnc
+
+
+def tree_order(group_adj_rank, term_rank, atom_rank, groups, terms,
+               xp=jnp):
+    """Full tree evaluation order: groups by (smoothed) rank, terms inside
+    each group by term rank, atoms inside each term by atom rank, ties by
+    user order. Returns (perm i32[P], group_perm i32[G]); groups and terms
+    stay contiguous in ``perm``.
+
+    Three composed stable sorts, least significant key first (as
+    ``cnf_order``): atom rank, then each atom's term position in the
+    global term-rank order, then its group's position in the group order
+    (terms never span groups, so the term key only breaks ties inside a
+    group).
+    """
+    garr = xp.asarray(list(groups), xp.int32)
+    tarr = xp.asarray(list(terms), xp.int32)
+    group_perm = argsort_stable(group_adj_rank, xp=xp).astype(xp.int32)
+    group_pos = argsort_stable(group_perm, xp=xp)
+    term_pos = argsort_stable(argsort_stable(term_rank, xp=xp), xp=xp)
+    perm = argsort_stable(atom_rank, xp=xp)
+    perm = perm[argsort_stable(term_pos[tarr][perm], xp=xp)]
+    perm = perm[argsort_stable(group_pos[garr][perm], xp=xp)]
+    return perm.astype(xp.int32), group_perm
+
+
 def expected_chain_cost(costs, pass_probs, perm, xp=jnp):
-    """Σ_i c_{perm[i]} Π_{j<i} s_{perm[j]} — the quantity rank order minimizes."""
+    """Σ_i c_{perm[i]} Π_{j<i} s_{perm[j]} — the quantity rank order minimizes
+    (with s the probability that evaluation goes on past a unit: its pass
+    rate in an AND, its miss rate in an OR)."""
     c = costs[perm]
     s = pass_probs[perm]
     surv = xp.concatenate([xp.ones((1,), s.dtype), xp.cumprod(s)[:-1]])

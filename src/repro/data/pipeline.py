@@ -88,12 +88,13 @@ def fstate_to_arrays(fstate) -> dict:
     """OrderState → flat dict of numpy arrays (checkpoint encoding).
 
     Works for single [P]-shaped states and stacked [S, P] sharded states —
-    leaves are stored verbatim, stats fields under a ``stats.`` prefix.
+    leaves are stored verbatim, stats fields under a ``stats.`` prefix
+    (``stats.term_cut`` only for trees).
     """
     return {k: np.asarray(v) for k, v in fstate._asdict().items()
             if k != "stats"} \
         | {f"stats.{k}": np.asarray(v) for k, v in
-           fstate.stats._asdict().items()}
+           fstate.stats._asdict().items() if v is not None}
 
 
 def fstate_from_arrays(fs: dict):
@@ -115,7 +116,9 @@ def fstate_from_arrays(fs: dict):
                         jnp.asarray(fs["stats.cost_acc"]),
                         jnp.asarray(fs["stats.n_monitored"]),
                         jnp.asarray(fs.get("stats.group_cut",
-                                           fs["stats.num_cut"])))
+                                           fs["stats.num_cut"])),
+                        None if "stats.term_cut" not in fs
+                        else jnp.asarray(fs["stats.term_cut"]))
     default_gperm = np.broadcast_to(
         np.arange(n_groups, dtype=np.int32), adj.shape)
     return OrderState(

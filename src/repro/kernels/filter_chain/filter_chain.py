@@ -11,13 +11,15 @@ adaptation (DESIGN §3) processes rows in VMEM tiles:
     CNF structure (OR within a group, AND across groups) is tracked with a
     running per-tile OR accumulator: members of the open group only
     evaluate rows not yet passed (vector analogue of the OR short-circuit),
-    and when a group closes its accumulator ANDs into the running mask;
+    and when a group closes its accumulator ANDs into the running mask; a
+    tree (AND-terms inside groups, static ``terms``) adds a term AND
+    accumulator, so an atom only evaluates rows its term has not failed;
   * when a tile has no pending rows for a position, that predicate is
     SKIPPED for the tile (``lax.cond`` — tile-granular short-circuit, the
     vector analogue of the row-level early exit);
   * the monitor lane (paper §2.1) evaluates ALL predicates on
-    stride-sampled rows and emits per-tile numCut / per-GROUP cut /
-    monitored counts;
+    stride-sampled rows and emits per-tile numCut / per-GROUP cut (and,
+    for a tree, per-TERM cut) / monitored counts;
   * per-tile ``active_before`` counters reproduce the row-level work model
     exactly (they count rows pending before each chain position), so the
     paper's cost accounting survives vectorization bit-exactly.
@@ -67,8 +69,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import predicates as pred_lib
+from repro.core.engine.base import CHAIN_TILE
 
-DEFAULT_TILE = 2048  # rows per grid step; multiple of 128 (VPU lanes)
+DEFAULT_TILE = CHAIN_TILE  # rows per grid step; multiple of 128 (VPU lanes)
 STAT_TILE = 128      # zone-map statistics granularity (= skip_tier.SKIP_TILE)
 LANES = 128
 
@@ -153,6 +156,7 @@ def _eval_pred(x, op, t1, t2, rounds):
         lambda: _lanes(jnp.logical_and(x > t1, x < t2)),
         lambda: _lanes(jnp.round(x) == jnp.round(t1)),
         _hashmix,
+        lambda: _lanes(pred_lib.in_mask(x, t1, t2)),
     ])
 
 
@@ -195,16 +199,22 @@ def _kernel(# --- SMEM scalar/spec refs ---
             meta_ref,  # i32[4]: (n_rows, collect_rate, sample_phase, mode)
             # --- skip-tier SMEM blocks (skip=True), VMEM data, outputs,
             # scratch ---
-            *refs,  # [pass_ref, fail_ref,] cols_ref, mask_ref, active_ref,
-                    # cut_ref, gcut_ref, nmon_ref [, out_hbm, cnt_ref],
-                    # xs_ref [, stage_ref, buf_ref, sem, state_ref]
-            n_preds: int, tile: int, groups: tuple, fill: float = 0.0,
-            skip: bool = False, compact: bool = False, out_blocks: int = 0):
+            *refs,  # [term_ref,] [pass_ref, fail_ref,] cols_ref, mask_ref,
+                    # active_ref, cut_ref, gcut_ref, nmon_ref [, tcut_ref]
+                    # [, out_hbm, cnt_ref], xs_ref [, stage_ref, buf_ref,
+                    # sem, state_ref]
+            n_preds: int, tile: int, groups: tuple, terms: tuple = (),
+            fill: float = 0.0, skip: bool = False, compact: bool = False,
+            out_blocks: int = 0):
+    if terms:
+        term_ref, refs = refs[0], refs[1:]
     if skip:
         pass_ref, fail_ref = refs[0], refs[1]
         refs = refs[2:]
     cols_ref, mask_ref, active_ref, cut_ref, gcut_ref, nmon_ref = refs[:6]
     refs = refs[6:]
+    if terms:
+        tcut_ref, refs = refs[0], refs[1:]
     if compact:
         out_hbm, cnt_ref, xs_ref, stage_ref, buf_ref, sem, state_ref = refs
     else:
@@ -253,33 +263,40 @@ def _kernel(# --- SMEM scalar/spec refs ---
     # boundary flag b selects by arithmetic (x·(1-b) + y·b), never a select
     # over bool vectors.
     mask = valid if not skip else valid * (1.0 - decided)
-    group_or = None                          # passes within the open group
-    for k in range(n_preds):                 # P static → unrolled on-chip
-        pidx = perm_ref[k]
-        # group-boundary flags: static when flat; dynamic SMEM scalar
-        # comparisons otherwise (the permutation is data-dependent).
-        if flat or k == 0:
-            pending = mask
-        else:
-            first = jnp.where(group_ref[perm_ref[k - 1]] != group_ref[pidx],
-                              1.0, 0.0)
-            group_or = group_or * (1.0 - first)
-            pending = mask * (1.0 - group_or)
-        alive = jnp.sum(pending)
-        active_ref[0, k] = alive
-        res = jax.lax.cond(
-            alive > 0.0,
-            lambda: _eval_pred(xs_ref[col_ref[pidx]], op_ref[pidx],
-                               t1_ref[pidx], t2_ref[pidx], rounds_ref[pidx]),
-            lambda: jnp.zeros(shape, jnp.float32),   # tile short-circuit
-        )
-        group_or = res if (flat or k == 0) else jnp.maximum(group_or, res)
-        if flat or k == n_preds - 1:
-            mask = mask * group_or
-        else:
-            closes = jnp.where(group_ref[perm_ref[k + 1]] != group_ref[pidx],
-                               1.0, 0.0)
-            mask = mask * (1.0 - closes * (1.0 - group_or))
+    if terms:
+        mask = _tree_lane(mask, xs_ref, col_ref, op_ref, t1_ref, t2_ref,
+                          rounds_ref, perm_ref, group_ref, term_ref,
+                          active_ref, n_preds=n_preds, shape=shape)
+    else:
+        group_or = None                      # passes within the open group
+        for k in range(n_preds):             # P static → unrolled on-chip
+            pidx = perm_ref[k]
+            # group-boundary flags: static when flat; dynamic SMEM scalar
+            # comparisons otherwise (the permutation is data-dependent).
+            if flat or k == 0:
+                pending = mask
+            else:
+                first = jnp.where(
+                    group_ref[perm_ref[k - 1]] != group_ref[pidx], 1.0, 0.0)
+                group_or = group_or * (1.0 - first)
+                pending = mask * (1.0 - group_or)
+            alive = jnp.sum(pending)
+            active_ref[0, k] = alive
+            res = jax.lax.cond(
+                alive > 0.0,
+                lambda: _eval_pred(xs_ref[col_ref[pidx]], op_ref[pidx],
+                                   t1_ref[pidx], t2_ref[pidx],
+                                   rounds_ref[pidx]),
+                lambda: jnp.zeros(shape, jnp.float32),  # tile short-circuit
+            )
+            group_or = res if (flat or k == 0) \
+                else jnp.maximum(group_or, res)
+            if flat or k == n_preds - 1:
+                mask = mask * group_or
+            else:
+                closes = jnp.where(
+                    group_ref[perm_ref[k + 1]] != group_ref[pidx], 1.0, 0.0)
+                mask = mask * (1.0 - closes * (1.0 - group_or))
     if skip:
         # bulk-keep provably-passing sub-tiles (valid rows only — zero
         # padding can satisfy a proof but never survives); the fused
@@ -312,6 +329,7 @@ def _kernel(# --- SMEM scalar/spec refs ---
     members: list[list[int]] = [[] for _ in range(max(groups) + 1)]
     for i, g in enumerate(groups):
         members[g].append(i)
+    term_members, group_terms = pred_lib.tree_structure(groups, terms)
 
     @pl.when(n_sampled > 0.0)
     def _monitor():
@@ -322,7 +340,18 @@ def _kernel(# --- SMEM scalar/spec refs ---
             fail = 1.0 - res
             fails.append(fail)
             cut_ref[0, p] = jnp.sum(sampled * fail)
-        for gi, mem in enumerate(members):   # static group reduction
+        if terms:                            # a group's members are terms
+            tfails = []
+            for ti, mem in enumerate(term_members):
+                tpass = 1.0 - fails[mem[0]]
+                for m in mem[1:]:
+                    tpass = tpass * (1.0 - fails[m])
+                tfails.append(1.0 - tpass)
+                tcut_ref[0, ti] = jnp.sum(sampled * tfails[-1])
+            fails, members_of = tfails, group_terms
+        else:
+            members_of = members
+        for gi, mem in enumerate(members_of):   # static group reduction
             gfail = fails[mem[0]]
             for m in mem[1:]:
                 gfail = gfail * fails[m]
@@ -334,6 +363,52 @@ def _kernel(# --- SMEM scalar/spec refs ---
             cut_ref[0, p] = 0.0
         for gi in range(len(members)):
             gcut_ref[0, gi] = 0.0
+        for ti in range(len(term_members)):
+            tcut_ref[0, ti] = 0.0
+
+
+def _tree_lane(mask, xs_ref, col_ref, op_ref, t1_ref, t2_ref, rounds_ref,
+               perm_ref, group_ref, term_ref, active_ref, *, n_preds: int,
+               shape):
+    """The chain lane of a tree (AND-terms inside OR-groups).
+
+    ``term_and`` holds the rows every atom of the open term has passed so
+    far, ``group_or`` the rows a closed term of the open group passed, so
+    a row is pending for position k when it is alive, its group has not
+    passed yet and its term has not failed yet — the tile short-circuit
+    then also fires inside a term. Term and group boundaries are dynamic
+    SMEM comparisons of the perm-ordered ids, selected by arithmetic as in
+    the CNF lane. Returns the mask after the last group closes.
+    """
+    group_or = jnp.zeros(shape, jnp.float32)
+    term_and = jnp.ones(shape, jnp.float32)
+    for k in range(n_preds):
+        pidx = perm_ref[k]
+        if k > 0:
+            prev = perm_ref[k - 1]
+            first_g = jnp.where(group_ref[prev] != group_ref[pidx], 1.0, 0.0)
+            first_t = jnp.where(term_ref[prev] != term_ref[pidx], 1.0, 0.0)
+            group_or = group_or * (1.0 - first_g)
+            term_and = term_and * (1.0 - first_t) + first_t
+        pending = mask * (1.0 - group_or) * term_and
+        alive = jnp.sum(pending)
+        active_ref[0, k] = alive
+        res = jax.lax.cond(
+            alive > 0.0,
+            lambda: _eval_pred(xs_ref[col_ref[pidx]], op_ref[pidx],
+                               t1_ref[pidx], t2_ref[pidx], rounds_ref[pidx]),
+            lambda: jnp.zeros(shape, jnp.float32),   # tile short-circuit
+        )
+        term_and = term_and * res
+        if k == n_preds - 1:
+            closes_t = closes_g = 1.0
+        else:
+            nxt = perm_ref[k + 1]
+            closes_t = jnp.where(term_ref[nxt] != term_ref[pidx], 1.0, 0.0)
+            closes_g = jnp.where(group_ref[nxt] != group_ref[pidx], 1.0, 0.0)
+        group_or = jnp.maximum(group_or, closes_t * term_and)
+        mask = mask * (1.0 - closes_g * (1.0 - group_or))
+    return mask
 
 
 def _compact_tile(t, mask, xs_ref, out_hbm, cnt_ref, stage_ref, buf_ref, sem,
@@ -455,7 +530,9 @@ def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
              cut f32[n_tiles, 1, P], gcut f32[n_tiles, 1, G],
              nmon f32[n_tiles, 1, 1]); with ``compact=True`` additionally
     (packed f32[C, capacity] — the survivors in row order, ``fill`` tail,
-    survivors past ``capacity`` dropped — and cnt i32[n_tiles, 1, 1]).
+    survivors past ``capacity`` dropped — and cnt i32[n_tiles, 1, 1]); for
+    a tree (``specs.term`` set) lastly tcut f32[n_tiles, 1, T], the
+    monitor lane's rows cut per AND-term.
     """
     n_cols, n_rows_p = columns.shape
     if n_rows_p % tile or tile % LANES:
@@ -467,6 +544,7 @@ def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
     sub = tile // LANES
     n_preds = int(specs.column.shape[0])
     groups = specs.groups                    # static tuple (pytree aux)
+    terms = specs.term                       # static; () → no terms
     n_groups = max(groups) + 1
     garr = jnp.asarray(groups, jnp.int32)
 
@@ -480,6 +558,8 @@ def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
              jax.ShapeDtypeStruct((n_tiles, sub, LANES), jnp.int8)),
             counters(n_preds), counters(n_preds), counters(n_groups),
             counters(1)]
+    if terms:
+        outs.append(counters(specs.n_terms))
     scratch = [pltpu.VMEM((n_cols, sub, LANES), jnp.float32)]
     out_blocks = 0
     if compact:
@@ -496,11 +576,15 @@ def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
 
     skip = skip_decisions is not None
     kernel = functools.partial(_kernel, n_preds=n_preds, tile=tile,
-                               groups=groups, fill=fill, skip=skip,
-                               compact=compact, out_blocks=out_blocks)
+                               groups=groups, terms=terms, fill=fill,
+                               skip=skip, compact=compact,
+                               out_blocks=out_blocks)
     in_specs = [smem()] * 8
     args = [specs.column, specs.op, specs.t1, specs.t2, specs.rounds, perm,
             garr, meta]
+    if terms:
+        in_specs.append(smem())
+        args.append(jnp.asarray(terms, jnp.int32))
     if skip:
         in_specs += [smem((None, 1, sub), lambda i: (i, 0, 0))] * 2
         args += [d.reshape(n_tiles, 1, sub) for d in skip_decisions]
@@ -519,6 +603,8 @@ def filter_chain_pallas(columns: jnp.ndarray, specs, perm: jnp.ndarray,
         interpret=interpret,
         name="adaptive_filter_chain_skip" if skip else "adaptive_filter_chain",
     )(*args)
+    res = list(res)
+    tcut = res.pop(5) if terms else None
     if compact:
-        res = (*res[:5], res[5][:n_cols, :capacity], res[6])
-    return res
+        res[5] = res[5][:n_cols, :capacity]
+    return (*res, tcut) if terms else tuple(res)

@@ -36,8 +36,11 @@ def _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode):
                                   jnp.int32)])
 
 
-def _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm, n_rows,
-                   skip: SkipInfo | None = None):
+def _reduce_result(outs, specs, perm, n_rows, skip: SkipInfo | None = None):
+    """``ChainResult`` from the launch's per-tile outputs (mask, active,
+    cut, gcut, nmon, and a tree's tcut last)."""
+    mask_i8, active, cut, gcut, nmon = outs[:5]
+    tcut = outs[-1] if specs.is_tree else None
     active_before = jnp.sum(active[:, 0], axis=0)            # f32[P]
     cost_in_order = specs.static_cost[perm]
     work = jnp.sum(active_before * cost_in_order)
@@ -60,6 +63,11 @@ def _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm, n_rows,
         n_tiles_pass=n_pass_t,
         n_tiles_fail=n_fail_t,
         n_tiles_ambiguous=n_amb_t,
+        term_cut_counts=jnp.zeros((0,), jnp.float32) if tcut is None
+        else jnp.sum(tcut[:, 0], axis=0),
+        # the tile short-circuit's ``alive > 0`` cond took the evaluating
+        # branch exactly where a tile had rows pending
+        n_atom_tiles=jnp.sum((active > 0.0).astype(jnp.int32)),
     )
 
 
@@ -125,12 +133,11 @@ def filter_chain(columns: jnp.ndarray, specs: PredicateSpecs,
         columns = jnp.pad(columns, ((0, 0), (0, pad)))
     meta = _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode)
 
-    mask_i8, active, cut, gcut, nmon = filter_chain_pallas(
+    outs = filter_chain_pallas(
         columns, specs, perm.astype(jnp.int32), meta, tile=tile,
         interpret=_should_interpret())
 
-    return _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm,
-                          n_rows)
+    return _reduce_result(outs, specs, perm, n_rows)
 
 
 @functools.partial(jax.jit,
@@ -160,14 +167,13 @@ def filter_chain_compact(columns: jnp.ndarray, specs: PredicateSpecs,
         columns = jnp.pad(columns, ((0, 0), (0, pad)))
     meta = _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode)
 
-    mask_i8, active, cut, gcut, nmon, packed, tile_cnt = \
-        filter_chain_pallas(columns, specs, perm.astype(jnp.int32), meta,
-                            tile=tile, interpret=_should_interpret(),
-                            compact=True, capacity=capacity, fill=fill)
+    outs = filter_chain_pallas(columns, specs, perm.astype(jnp.int32), meta,
+                               tile=tile, interpret=_should_interpret(),
+                               compact=True, capacity=capacity, fill=fill)
+    packed, tile_cnt = outs[5:7]
     n_kept = jnp.minimum(jnp.sum(tile_cnt), capacity).astype(jnp.int32)
 
-    result = _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm,
-                            n_rows)
+    result = _reduce_result(outs, specs, perm, n_rows)
     return result, packed, n_kept
 
 
@@ -191,12 +197,11 @@ def filter_chain_skip(columns: jnp.ndarray, specs: PredicateSpecs,
     columns = _pad_cols(columns, tile)
     meta = _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode)
 
-    mask_i8, active, cut, gcut, nmon = filter_chain_pallas(
+    outs = filter_chain_pallas(
         columns, specs, perm.astype(jnp.int32), meta, tile=tile,
         interpret=_should_interpret(), skip_decisions=_skip_decisions(skip))
 
-    return _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm,
-                          n_rows, skip=skip)
+    return _reduce_result(outs, specs, perm, n_rows, skip=skip)
 
 
 @functools.partial(jax.jit,
@@ -222,13 +227,12 @@ def filter_chain_compact_skip(columns: jnp.ndarray, specs: PredicateSpecs,
     columns = _pad_cols(columns, tile)
     meta = _pack_meta(n_rows, collect_rate, sample_phase, monitor_mode)
 
-    mask_i8, active, cut, gcut, nmon, packed, tile_cnt = \
-        filter_chain_pallas(columns, specs, perm.astype(jnp.int32), meta,
-                            tile=tile, interpret=_should_interpret(),
-                            compact=True, capacity=capacity, fill=fill,
-                            skip_decisions=_skip_decisions(skip))
+    outs = filter_chain_pallas(columns, specs, perm.astype(jnp.int32), meta,
+                               tile=tile, interpret=_should_interpret(),
+                               compact=True, capacity=capacity, fill=fill,
+                               skip_decisions=_skip_decisions(skip))
+    packed, tile_cnt = outs[5:7]
     n_kept = jnp.minimum(jnp.sum(tile_cnt), capacity).astype(jnp.int32)
 
-    result = _reduce_result(mask_i8, active, cut, gcut, nmon, specs, perm,
-                            n_rows, skip=skip)
+    result = _reduce_result(outs, specs, perm, n_rows, skip=skip)
     return result, packed, n_kept

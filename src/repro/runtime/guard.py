@@ -446,7 +446,8 @@ class GuardedSession:
             work_units=np.zeros((), np.float32), n_pass=z32,
             perm=np.asarray(state.perm), epoch=np.asarray(state.epoch),
             adj_rank=np.asarray(state.adj_rank), n_dropped=z32,
-            n_tiles_pass=z32, n_tiles_fail=z32, n_tiles_ambiguous=z32)
+            n_tiles_pass=z32, n_tiles_fail=z32, n_tiles_ambiguous=z32,
+            n_atom_tiles=z32)
         return StepResult(np.zeros((n_rows,), bool), None, None, None, None,
                           metrics, None, warn_cell=None, quarantined=True,
                           gate_s=0.0)

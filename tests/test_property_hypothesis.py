@@ -69,7 +69,7 @@ def test_outcome_order_invariant_all_backends(seed, perm):
                      collect_rate=97, sample_phase=0)
     permuted = run_chain(jnp.asarray(cols), specs, jperm,
                          collect_rate=97, sample_phase=0)
-    np_mask, _, _ = np_exec.run_chain_np(cols, preds, perm)
+    np_mask = np_exec.run_chain_np(cols, preds, perm)[0]
     assert np.array_equal(np.asarray(base.mask), np.asarray(permuted.mask))
     assert np.array_equal(np.asarray(base.mask), np_mask)
 

@@ -5,7 +5,8 @@ Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 misaligned blocks, scalar stores to VMEM, bool vectors through
 ``cond``/``select``, dynamic lane offsets, VMEM overuse. Each test here
 lowers one launch of the main path for one v5e chip — 2^22 rows, the
-paper chain's 3 columns and the guardrail chain's 4 — and asserts the
+paper chain's 3 columns, the guardrail chain's 4 and TPC-H Q19's tree of
+AND-terms and IN atoms over 8 — and asserts the
 compiled program holds the kernel as a ``tpu_custom_call``. The topology
 is described inside the module fixture (never at import), and every
 compile runs in the test's own process.
@@ -14,6 +15,7 @@ compile runs in the test's own process.
 import importlib
 
 import pytest
+from conftest import q19_filters
 
 jax = pytest.importorskip("jax")
 jnp = jax.numpy
@@ -50,6 +52,8 @@ def _chain(name):
     from repro.core.predicates import paper_filters_4
     from repro.serving.traffic import guardrail_chain
 
+    if name == "q19":
+        return pack(q19_filters())
     return pack(paper_filters_4("fig1") if name == "paper"
                 else guardrail_chain())
 
@@ -61,6 +65,7 @@ LAUNCHES = {
     "paper-skip": ("paper", 3, None, True),
     "paper-compact": ("paper", 3, ROWS, False),
     "guardrail-compact-skip": ("guardrail", 4, ROWS // 4, True),
+    "q19-tree-compact": ("q19", 8, ROWS, False),
 }
 
 
